@@ -1655,6 +1655,10 @@ def main():
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     # smoke trims only the slow legacy (per-token loop) stream; the new-path
     # stream and the continuous trace stay at full size so their tokens/s
     # remain comparable to the committed reference (a shorter run

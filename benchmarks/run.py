@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import json
 import time
+import traceback
 
 
 def _derived(rows):
@@ -48,6 +49,7 @@ def main() -> None:
     ]
     print("name,us_per_call,derived")
     all_rows = {}
+    failed = []
     for name, fn in benches:
         t0 = time.perf_counter()
         try:
@@ -55,14 +57,18 @@ def main() -> None:
             us = (time.perf_counter() - t0) * 1e6
             print(f"{name},{us:.0f},{_derived(rows)}", flush=True)
             all_rows[name] = rows
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - report every figure, then fail
             us = (time.perf_counter() - t0) * 1e6
             print(f"{name},{us:.0f},ERROR:{type(e).__name__}", flush=True)
+            traceback.print_exc()
+            failed.append(name)
     import pathlib
 
     out = pathlib.Path("results/benchmarks.json")
     out.parent.mkdir(exist_ok=True)
     out.write_text(json.dumps(all_rows, indent=1, default=str))
+    if failed:
+        raise SystemExit(f"{len(failed)} figure(s) raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1,4 +1,4 @@
-"""Roofline model for TPU v5e from compiled dry-run artifacts.
+"""Roofline model from compiled dry-run artifacts (planning target: TPU v5e).
 
 compute term    = HLO_FLOPs / (chips * 197 TFLOP/s)
 memory term     = HLO_bytes / (chips * 819 GB/s)
@@ -24,10 +24,41 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-# --- hardware constants (TPU v5e, per brief) -------------------------------
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
+# --- hardware peaks, keyed by jax Device.device_kind ----------------------
+
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops: float       # bf16 FLOP/s per chip
+    hbm_bw: float      # HBM bytes/s per chip
+    ici_bw: float      # bytes/s per ICI link
+
+
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB
+# HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip (4 links x 50 GB/s).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+# CPU-side analytical planning (dry-run rooflines, knee and DPU cost
+# models) plans for this chip, whatever device the planner runs on.
+PLANNING_TARGET = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """Published peaks of `device_kind`; a kind not in PEAKS is an error,
+    never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to roofline.PEAKS with their source") from None
+
+
+PEAK_FLOPS = PEAKS[PLANNING_TARGET].flops
+HBM_BW = PEAKS[PLANNING_TARGET].hbm_bw
+ICI_BW = PEAKS[PLANNING_TARGET].ici_bw
 ICI_LINKS = 1              # conservative single-link assumption (documented)
 
 _DTYPE_BYTES = {

@@ -23,6 +23,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.analysis.roofline import HBM_BW, PEAK_FLOPS
+
 
 @dataclass
 class FunctionalUnit:
@@ -73,12 +75,12 @@ class ComputeUnit:
 
 
 # ---------------------------------------------------------------------------
-# Cost models (TPU v5e DPU kernels; analytical, documented in EXPERIMENTS.md)
+# Cost models (DPU kernels on the planning target, roofline.PLANNING_TARGET)
 # ---------------------------------------------------------------------------
 
-_MXU_FLOPS = 197e12 * 0.3   # preprocessing kernels are small-matmul bound;
-                            # 30% MXU efficiency assumption for tiny tiles
-_VPU_BYTES = 819e9          # element-wise ops stream at HBM bandwidth
+_MXU_FLOPS = PEAK_FLOPS * 0.3  # preprocessing kernels are small-matmul
+                               # bound; 30% MXU efficiency for tiny tiles
+_VPU_BYTES = HBM_BW            # element-wise ops stream at HBM bandwidth
 _FIXED_OVERHEAD = 20e-6     # per-kernel dispatch overhead (tens of us)
 
 

@@ -1,28 +1,8 @@
 """Ambient mesh context for model code that needs explicit collectives
-(shard_map MoE). Set by step factories / engines before tracing.
-
-Also hosts the shard_map version shim: jax.shard_map(check_vma=...) only
-exists on newer jax; older releases expose jax.experimental.shard_map with
-check_rep instead."""
+(shard_map MoE). Set by step factories / engines before tracing."""
 from __future__ import annotations
 
 from contextlib import contextmanager
-from typing import Optional
-
-import jax
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
 
 _MESH = None
 

@@ -72,13 +72,12 @@ def pipeline_apply(
         )
         return outs
 
-    from repro.distributed.ctx import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
+        check_vma=False,
     )(stage_params, microbatches)
 
 

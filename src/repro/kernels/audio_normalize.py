@@ -59,7 +59,7 @@ def _scale_kernel_b(feats_ref, mu_ref, inv_ref, out_ref):
 
 
 def audio_normalize_batch_pallas(feats: jax.Array, *, eps: float = 1e-5,
-                                 interpret: bool = True) -> jax.Array:
+                                 interpret: bool) -> jax.Array:
     """feats: [N, T, F] stack of same-shape utterances -> per-utterance
     mean/var normalized [N, T, F]. One stats launch + one scale launch for
     the whole stack (grid (N, T-tiles)) instead of 2N per-request launches."""
@@ -102,7 +102,7 @@ def audio_normalize_batch_pallas(feats: jax.Array, *, eps: float = 1e-5,
 
 
 def audio_normalize_pallas(feats: jax.Array, *, eps: float = 1e-5,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """feats: [T, F] -> per-utterance mean/var normalized [T, F]."""
     t, f = feats.shape
     nb = pl.cdiv(t, BLOCK_T)

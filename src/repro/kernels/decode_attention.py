@@ -3,8 +3,7 @@
 One query token per sequence against a long KV cache: the per-chip cost is
 HBM-bound cache reads, so the kernel streams KV blocks through VMEM with an
 online-softmax accumulator held in VMEM scratch. Grid = (batch, kv_head,
-S/BLOCK_S); for GQA all G query heads of a kv head ride in one [G, D] tile —
-MXU-aligned when G*D is a multiple of 128 (e.g. yi: 7x128).
+S/BLOCK_S); for GQA all G query heads of a kv head ride in one [G, D] tile.
 """
 from __future__ import annotations
 
@@ -18,7 +17,7 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK_S = 512
 
 
-def _decode_attn_kernel(scale, q_ref, k_ref, v_ref, vlen_ref, o_ref,
+def _decode_attn_kernel(scale, vlen_ref, q_ref, k_ref, v_ref, o_ref,
                         m_ref, l_ref, acc_ref):
     s_idx = pl.program_id(2)
     ns = pl.num_programs(2)
@@ -30,11 +29,13 @@ def _decode_attn_kernel(scale, q_ref, k_ref, v_ref, vlen_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)         # [G, D]
-    k = k_ref[0, :, 0].astype(jnp.float32)      # [BLOCK_S, D]
-    v = v_ref[0, :, 0].astype(jnp.float32)      # [BLOCK_S, D]
-    vlen = vlen_ref[0]
+    k = k_ref[0, 0].astype(jnp.float32)         # [BLOCK_S, D]
+    v = v_ref[0, 0].astype(jnp.float32)         # [BLOCK_S, D]
+    vlen = vlen_ref[pl.program_id(0)]
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # [G, S_blk]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale                                   # [G, BLOCK_S]
     pos = s_idx * BLOCK_S + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(pos < vlen, s, -1e30)
 
@@ -54,37 +55,43 @@ def _decode_attn_kernel(scale, q_ref, k_ref, v_ref, vlen_ref, o_ref,
 
 
 def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
-                            valid_len: jax.Array, *, interpret: bool = True) -> jax.Array:
+                            valid_len: jax.Array, *, interpret: bool) -> jax.Array:
     """q: [B, H, D]; k,v: [B, S, KH, D]; valid_len: [B] -> out [B, H, D].
 
-    Prefix-valid cache layout (slots [0, valid_len) hold keys)."""
+    Prefix-valid cache layout (slots [0, valid_len) hold keys). The kernel
+    reads the cache as [B, KH, S, D], so each K/V block is a
+    (BLOCK_S, D) tile; valid_len rides in SMEM via scalar prefetch."""
     B, H, D = q.shape
     S, KH = k.shape[1], k.shape[2]
     G = H // KH
     qg = q.reshape(B, KH, G, D)
     ns = pl.cdiv(S, BLOCK_S)
+    kt = jnp.swapaxes(k, 1, 2)
+    vt = jnp.swapaxes(v, 1, 2)
     if ns * BLOCK_S != S:
-        pad = ns * BLOCK_S - S
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        pad = ((0, 0), (0, 0), (0, ns * BLOCK_S - S), (0, 0))
+        kt = jnp.pad(kt, pad)
+        vt = jnp.pad(vt, pad)
     scale = 1.0 / (D ** 0.5)
 
     out = pl.pallas_call(
         functools.partial(_decode_attn_kernel, scale),
-        grid=(B, KH, ns),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, BLOCK_S, 1, D), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, BLOCK_S, 1, D), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1,), lambda b, h, s: (b,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, s: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, KH, ns),
+            in_specs=[
+                pl.BlockSpec((1, 1, G, D), lambda b, h, s, vl: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, BLOCK_S, D), lambda b, h, s, vl: (b, h, s, 0)),
+                pl.BlockSpec((1, 1, BLOCK_S, D), lambda b, h, s, vl: (b, h, s, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, s, vl: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, D), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
         interpret=interpret,
-    )(qg, k, v, valid_len.astype(jnp.int32))
+    )(valid_len.astype(jnp.int32), qg, kt, vt)
     return out.reshape(B, H, D)

@@ -11,17 +11,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 TILE = 128
+_HI = jax.lax.Precision.HIGHEST   # f32 pixels: full-precision MXU passes
 
 
 def _rows_kernel(ry_ref, img_ref, out_ref):
     out_ref[...] = jnp.dot(
-        ry_ref[...], img_ref[...], preferred_element_type=jnp.float32
+        ry_ref[...], img_ref[...], precision=_HI,
+        preferred_element_type=jnp.float32
     )
 
 
 def _cols_kernel(tmp_ref, rxt_ref, out_ref):
     out_ref[...] = jnp.dot(
-        tmp_ref[...], rxt_ref[...], preferred_element_type=jnp.float32
+        tmp_ref[...], rxt_ref[...], precision=_HI,
+        preferred_element_type=jnp.float32
     )
 
 
@@ -32,12 +35,13 @@ def _pad_rows(a, mult):
 
 def _rows_kernel_b(ry_ref, img_ref, out_ref):
     out_ref[0] = jnp.dot(
-        ry_ref[...], img_ref[0], preferred_element_type=jnp.float32
+        ry_ref[...], img_ref[0], precision=_HI,
+        preferred_element_type=jnp.float32
     )
 
 
 def image_resize_batch_pallas(imgs: jax.Array, ry: jax.Array, rx: jax.Array, *,
-                              interpret: bool = True) -> jax.Array:
+                              interpret: bool) -> jax.Array:
     """imgs: [N, H, W] same-shape stack -> [N, H_out, W_out]. The row pass
     runs as one launch over grid (N, row-tiles); the column pass flattens the
     stack to [N*H_out, W] rows — two launches total for the whole stack."""
@@ -74,7 +78,7 @@ def image_resize_batch_pallas(imgs: jax.Array, ry: jax.Array, rx: jax.Array, *,
 
 
 def image_resize_pallas(img: jax.Array, ry: jax.Array, rx: jax.Array, *,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """img: [H, W]; ry: [H_out, H]; rx: [W_out, W] -> [H_out, W_out]."""
     h, w = img.shape
     ryp, h_out = _pad_rows(ry.astype(jnp.float32), TILE)

@@ -15,20 +15,23 @@ from jax.experimental import pallas as pl
 from repro.data.preprocess_cpu import idct_matrix
 
 BLOCK_NB = 512
+_HI = jax.lax.Precision.HIGHEST   # f32 pixels: full-precision MXU passes
 
 
 def _idct_kernel(coeffs_ref, qtable_ref, m_ref, out_ref):
     m = m_ref[...]
     c = coeffs_ref[...].astype(jnp.float32) * qtable_ref[...][None]
     # two 8x8 matmuls per block: M @ c @ M^T, batched over the block dim
-    tmp = jnp.einsum("ij,bjk->bik", m, c, preferred_element_type=jnp.float32)
+    tmp = jnp.einsum("ij,bjk->bik", m, c, precision=_HI,
+                     preferred_element_type=jnp.float32)
     out_ref[...] = (
-        jnp.einsum("bik,lk->bil", tmp, m, preferred_element_type=jnp.float32) + 128.0
+        jnp.einsum("bik,lk->bil", tmp, m, precision=_HI,
+                   preferred_element_type=jnp.float32) + 128.0
     )
 
 
 def jpeg_idct_pallas(coeffs: jax.Array, qtable: jax.Array, *,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """coeffs: [NB, 8, 8] quantized blocks; qtable: [8, 8] -> pixels [NB, 8, 8]."""
     nb_total = coeffs.shape[0]
     nb = pl.cdiv(nb_total, BLOCK_NB)

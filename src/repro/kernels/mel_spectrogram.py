@@ -15,20 +15,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 FRAME_BLOCK = 128
+# f32 contraction on the MXU: at the default (one bf16 pass) the DFT's
+# rounding leaks into near-silent mel bands, whose logs then miss the
+# reference by whole units
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _mel_kernel(frames_ref, cr_ref, ci_ref, fb_ref, out_ref):
     f = frames_ref[...].astype(jnp.float32)
-    re = jnp.dot(f, cr_ref[...], preferred_element_type=jnp.float32)
-    im = jnp.dot(f, ci_ref[...], preferred_element_type=jnp.float32)
+    re = jnp.dot(f, cr_ref[...], precision=_HI,
+                 preferred_element_type=jnp.float32)
+    im = jnp.dot(f, ci_ref[...], precision=_HI,
+                 preferred_element_type=jnp.float32)
     power = re * re + im * im
     out_ref[...] = jnp.log(
-        jnp.dot(power, fb_ref[...], preferred_element_type=jnp.float32) + 1e-6
+        jnp.dot(power, fb_ref[...], precision=_HI,
+                preferred_element_type=jnp.float32) + 1e-6
     )
 
 
 def mel_spectrogram_pallas(frames: jax.Array, cr: jax.Array, ci: jax.Array,
-                           fb: jax.Array, *, interpret: bool = True) -> jax.Array:
+                           fb: jax.Array, *, interpret: bool) -> jax.Array:
     """frames: [N, n_fft] framed+windowed+zero-padded; cr/ci: [n_fft, F];
     fb: [F, n_mels] -> log-mel [N, n_mels]."""
     n, n_fft = frames.shape

@@ -1,5 +1,6 @@
-"""Jitted public wrappers for the DPU kernels. Auto-selects interpret mode
-off-TPU (this container validates kernels on CPU; TPU is the target)."""
+"""Jitted public wrappers for the DPU kernels. On the CPU backend the
+kernels run in Pallas interpret mode (the tests validate them there); on a
+TPU they compile to Mosaic. Any other backend is refused."""
 from __future__ import annotations
 
 import functools
@@ -12,7 +13,7 @@ import numpy as np
 
 from repro.data import preprocess_cpu as pp
 from repro.kernels.audio_normalize import audio_normalize_batch_pallas, audio_normalize_pallas
-from repro.kernels.audio_resample import audio_resample_batch_pallas, audio_resample_pallas
+from repro.kernels.audio_resample import audio_resample_batch_pallas
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.image_normalize import image_crop_normalize_pallas
 from repro.kernels.image_resize import image_resize_batch_pallas, image_resize_pallas
@@ -21,7 +22,12 @@ from repro.kernels.mel_spectrogram import mel_spectrogram_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"DPU kernels run on a TPU (or interpreted on the CPU), "
+            f"not on backend {backend!r}")
+    return backend == "cpu"
 
 
 # --- audio ------------------------------------------------------------------

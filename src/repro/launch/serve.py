@@ -192,6 +192,10 @@ def main():
                          "to this path after serving")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import numpy as np
 
     from repro.configs import get_config, reduced
@@ -351,6 +355,12 @@ def main():
             f"backpressure={rt.stats['shed_backpressure']}, "
             f"error={rt.stats['shed_error']}); {exec_ms}"
         )
+        # a degraded run must not look healthy: the breaker's CPU path and
+        # any failed DPU launch are printed next to the served count
+        print(f"  dpu: cpu_fallback={rt.stats['cpu_fallback']} "
+              f"breaker_trips={rt.stats['breaker_trips']} "
+              f"shed_error={rt.stats['shed_error']} "
+              f"last_error={service.last_error if service else None!r}")
         for stage, st in rt.stage_summary().items():
             print(f"  queue[{stage}]: mean={st['mean']:.2f} max={st['max']}")
         occ = rt.stage_occupancy()

@@ -440,21 +440,30 @@ def moe_shard_map(x, p, cfg, mesh):
     ep_spec = P("data", None, "model") if ep else P(None, dp, "model")
     ep_spec_o = P("data", "model", None) if ep else P(None, "model", dp)
     dt = x.dtype
-    from repro.distributed.ctx import shard_map as _shmap
-
-    y, aux = _shmap(
+    # on a mesh with Explicit axes the token<->group reshapes must name
+    # their output sharding (rows split over the data axes); Auto axes
+    # refuse one and let the partitioner choose
+    explicit = any(
+        t == jax.sharding.AxisType.Explicit
+        for a, t in zip(mesh.axis_names, mesh.axis_types) if a in dp
+    )
+    rows = jax.sharding.NamedSharding(mesh, P(dp, None, None)) \
+        if explicit else None
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None), ep_spec, ep_spec, ep_spec_o),
         out_specs=(P(dp, None, None), P()),
+        check_vma=False,
     )(
-        x.reshape(G, group, D),
+        jnp.reshape(x, (G, group, D), out_sharding=rows),
         p["router"].astype(jnp.float32),
         p["wi_gate"].astype(dt),
         p["wi_up"].astype(dt),
         p["wo"].astype(dt),
     )
-    return y.reshape(B, S, D), aux * cfg.router_aux_weight
+    y = jnp.reshape(y, (B, S, D), out_sharding=rows)
+    return y, aux * cfg.router_aux_weight
 
 
 def _dispatch_mask(gate_idx, gate_vals, E, C, dtype):

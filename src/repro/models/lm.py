@@ -354,10 +354,12 @@ def cache_axes(cfg: ModelConfig, batch: int, cache_len: int):
 # ---------------------------------------------------------------------------
 
 
-def alloc_slot_pool(cfg: ModelConfig, max_slots: int, cache_len: int):
-    """Zero-initialized slot-pool cache tree (shape [max_slots, cache_len])."""
+def alloc_slot_pool(cfg: ModelConfig, max_slots: int, cache_len: int,
+                    device=None):
+    """Zero-initialized slot-pool cache tree (shape [max_slots, cache_len])
+    on `device` (a Device or Sharding; None = the default device)."""
     return jax.tree.map(
-        lambda sd: jnp.zeros(sd.shape, sd.dtype),
+        lambda sd: jnp.zeros(sd.shape, sd.dtype, device=device),
         cache_specs(cfg, max_slots, cache_len),
     )
 

@@ -667,10 +667,17 @@ class ServingEngine:
         return sum(1 for s in self._slots if s is None)
 
     def _ensure_pool(self) -> None:
+        """Allocate the slot pool where the params live (a slice engine's
+        own devices), and send every later host input there directly."""
         if self._pool is None:
+            self._dev = jax.tree.leaves(self.params)[0].sharding
             self._pool = lm.alloc_slot_pool(
-                self.cfg, self.ec.max_slots, self.pool_len
+                self.cfg, self.ec.max_slots, self.pool_len, self._dev
             )
+
+    def _put(self, x) -> jax.Array:
+        """Host array -> this engine's devices (pool allocated first)."""
+        return jnp.asarray(x, device=self._dev)
 
     def _get_admit(self, lp: int):
         """Jitted prefill+admit executable, one per padded prompt length.
@@ -707,8 +714,8 @@ class ServingEngine:
         sids = np.full(bp, bp, np.int32)  # out-of-range rows -> dropped
         sids[: len(reqs)] = free[: len(reqs)]
         tok0, self._pool = self._get_admit(lp)(
-            self.params, jnp.asarray(toks), jnp.asarray(off), self._pool,
-            jnp.asarray(sids), jnp.int32(self._clock),
+            self.params, self._put(toks), self._put(off), self._pool,
+            self._put(sids), self._put(np.int32(self._clock)),
         )
         tok0 = np.asarray(tok0)
         t = self._stamp(now)
@@ -905,7 +912,7 @@ class ServingEngine:
 
             jax.tree.map(_put, batch, kv)
         self._pool = self._get_prefix_scatter(lp)(
-            self._pool, jax.tree.map(jnp.asarray, batch), jnp.asarray(sids)
+            self._pool, jax.tree.map(self._put, batch), self._put(sids)
         )
         self.tracer.event(
             tm.PREFIX_SCATTER, t, sid=self._sid, tenant=self._tenant,
@@ -967,8 +974,8 @@ class ServingEngine:
                 off[s] = adm.off[s]
                 start[s] = col
         tok0, self._pool = self._get_chunk(c, lp)(
-            self.params, jnp.asarray(toks), jnp.asarray(off), self._pool,
-            jnp.asarray(start),
+            self.params, self._put(toks), self._put(off), self._pool,
+            self._put(start),
         )
         exec_s = time.monotonic() - t0
         self.batch_exec_s.append(exec_s)
@@ -1017,8 +1024,9 @@ class ServingEngine:
                 self._pool_off[s] = self._clock - st.filled
         t0 = time.monotonic()
         toks, self._pool = self._segment_jit(
-            self.params, self._pool, jnp.asarray(self._tok),
-            jnp.int32(self._clock), jnp.asarray(self._pool_off), int(steps),
+            self.params, self._pool, self._put(self._tok),
+            self._put(np.int32(self._clock)), self._put(self._pool_off),
+            int(steps),
         )
         toks = np.asarray(toks)
         self._clock += steps

@@ -493,20 +493,18 @@ class MultiSliceEngine:
         return e
 
     def _params_for(self, ps: PodSlice, params):
-        """Replicate params onto the slice's mesh when it owns real devices;
-        logical replicas (CPU CI) share one param tree — no copies."""
+        """Replicate params onto the slice's own devices (one chip or a
+        sub-mesh); the engine then allocates its slot pool beside them.
+        Logical replicas (fewer devices than slices, CPU CI) share one
+        param tree — no copies."""
         import jax
 
-        if self.replicated or ps.devices.size <= 1:
+        if self.replicated:
             return params
-        try:
-            mesh = ps.make_mesh()
-            sharding = jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec()
-            )
-            return jax.device_put(params, sharding)
-        except Exception:
-            return params  # mesh/backends that can't place: share
+        sharding = jax.sharding.NamedSharding(
+            ps.make_mesh(), jax.sharding.PartitionSpec()
+        )
+        return jax.device_put(params, sharding)
 
     @property
     def hedges(self) -> int:

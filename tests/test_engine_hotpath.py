@@ -27,6 +27,17 @@ def _ragged_prompts(cfg, lens, seed=0):
     return [rng.integers(0, cfg.vocab, n).astype(np.int32) for n in lens]
 
 
+def _assert_logits_match(got, ref):
+    """Padding must not change a request's result: the greedy token is
+    exact, and the logits agree to within 4 ulps of the row's largest
+    logit (XLA may order a reduction differently for a different batch
+    shape, which moves the last bit; no semantic change moves only that)."""
+    got, ref = np.asarray(got), np.asarray(ref)
+    assert int(np.argmax(got)) == int(np.argmax(ref))
+    tol = 4 * np.spacing(np.abs(ref).max().astype(ref.dtype))
+    np.testing.assert_allclose(got, ref, rtol=0, atol=tol)
+
+
 def _left_pad(prompts, lp, bp):
     toks = np.zeros((bp, lp), np.int32)
     off = np.full(bp, lp, np.int32)
@@ -38,7 +49,8 @@ def _left_pad(prompts, lp, bp):
 
 def test_padded_prefill_matches_unpadded(tiny):
     """Left-padding to a (batch, len) bucket with pos_offset masking must not
-    change any request's last-token logits vs running it alone unpadded."""
+    change any request's last-token logits vs running it alone unpadded
+    (greedy token exact, logits within a few ulps)."""
     cfg, params = tiny
     steps, lp = 4, 16
     prompts = _ragged_prompts(cfg, [5, 12, 9])
@@ -50,7 +62,7 @@ def test_padded_prefill_matches_unpadded(tiny):
     toks, off = _left_pad(prompts, lp, 4)  # batch-padded 3 -> 4 rows
     logits, _ = lm.prefill(params, toks, cfg, pos_offset=off, cache_len=lp + steps)
     for i, ref in enumerate(refs):
-        np.testing.assert_array_equal(np.asarray(logits[i, 0]), ref)
+        _assert_logits_match(logits[i, 0], ref)
 
 
 def test_padded_decode_tokens_match_unpadded(tiny):
@@ -134,7 +146,7 @@ def test_padded_prefill_matches_unpadded_ssm_trained_biases():
     toks, off = _left_pad(prompts, lp, 2)
     logits, _ = lm.prefill(params, toks, cfg, pos_offset=off, cache_len=lp + steps)
     for i, ref in enumerate(refs):
-        np.testing.assert_array_equal(np.asarray(logits[i, 0]), ref)
+        _assert_logits_match(logits[i, 0], ref)
 
 
 def test_pos_offset_rejected_with_image_prefix():

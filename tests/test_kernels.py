@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps: pallas (interpret) vs pure-jnp oracle."""
+"""Per-kernel shape/dtype sweeps on the CPU: pallas (interpret mode) vs
+pure-jnp oracle."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,7 +24,8 @@ def test_mel_spectrogram_sweep(n_frames, n_mels):
     cr, ci = pp.dft_matrices(n_fft)
     fb = pp.mel_filterbank(n_mels, n_fft, 16000).T
     got = mel_spectrogram_pallas(
-        jnp.asarray(frames), jnp.asarray(cr), jnp.asarray(ci), jnp.asarray(fb)
+        jnp.asarray(frames), jnp.asarray(cr), jnp.asarray(ci), jnp.asarray(fb),
+        interpret=True,
     )
     want = ref.mel_spectrogram_ref(
         jnp.asarray(frames), jnp.asarray(cr), jnp.asarray(ci), jnp.asarray(fb)
@@ -35,7 +37,7 @@ def test_mel_spectrogram_sweep(n_frames, n_mels):
 @pytest.mark.parametrize("f", [80, 128])
 def test_audio_normalize_sweep(t, f):
     feats = (rng.standard_normal((t, f)) * 3 + 1).astype(np.float32)
-    got = audio_normalize_pallas(jnp.asarray(feats))
+    got = audio_normalize_pallas(jnp.asarray(feats), interpret=True)
     want = ref.audio_normalize_ref(jnp.asarray(feats))
     assert_allclose(np.asarray(got), np.asarray(want), rtol=3e-3, atol=3e-3)
 
@@ -54,7 +56,7 @@ def test_audio_resample_sweep(down, n):
 def test_jpeg_idct_sweep(nb):
     co = rng.integers(-64, 64, (nb, 8, 8)).astype(np.float32)
     qt = rng.integers(1, 32, (8, 8)).astype(np.float32)
-    got = jpeg_idct_pallas(jnp.asarray(co), jnp.asarray(qt))
+    got = jpeg_idct_pallas(jnp.asarray(co), jnp.asarray(qt), interpret=True)
     want = ref.jpeg_idct_ref(jnp.asarray(co), jnp.asarray(qt))
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-3)
 
@@ -88,7 +90,8 @@ def test_decode_attention_sweep(shape, dtype):
     v = rng.standard_normal((b, s, kh, d)).astype(np.float32)
     vl = rng.integers(1, s + 1, (b,)).astype(np.int32)
     qj, kj, vj = (jnp.asarray(a, dtype) for a in (q, k, v))
-    got = decode_attention_pallas(qj, kj, vj, jnp.asarray(vl))
+    got = decode_attention_pallas(qj, kj, vj, jnp.asarray(vl),
+                                  interpret=True)
     want = ref.decode_attention_ref(qj, kj, vj, jnp.asarray(vl))
     tol = 2e-4 if dtype == np.float32 else 3e-2
     assert_allclose(
@@ -115,5 +118,5 @@ def test_decode_attention_matches_model_attention():
                               jnp.array([s]), kp, causal=True, window=0)
         )
     want = jnp.concatenate(outs, 0)[:, 0]
-    got = decode_attention_pallas(q[:, 0], k, v, vl)
+    got = decode_attention_pallas(q[:, 0], k, v, vl, interpret=True)
     assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)

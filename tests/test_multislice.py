@@ -207,11 +207,14 @@ def test_resize_mid_trace_loses_no_requests(setup):
     assert ms.stats["resizes"] == 1
 
 
-def test_resize_by_menu_entry_on_partitioned_devices(setup):
+def test_resize_by_menu_entry_on_partitioned_devices(setup, monkeypatch):
     """With enough (fake) devices the pod really partitions: resize by
-    chips_per_slice walks the partition menu, and the engines fall back to
-    shared params when the fake devices can't host a mesh."""
+    chips_per_slice walks the partition menu. Integer stand-ins cannot hold
+    arrays, so here every slice shares the one param tree (placement on
+    real devices: test_slices_place_params_and_pool_on_own_devices)."""
     cfg, params, ref = setup
+    monkeypatch.setattr(MultiSliceEngine, "_params_for",
+                        lambda self, ps, p: p)
     ms = MultiSliceEngine(cfg, params, _policy(4), _ec(), n_slices=4,
                           devices=list(range(64)))
     assert not ms.replicated
@@ -481,3 +484,63 @@ def test_plan_placement_fragmentation_accounting():
     assert q.stranded_chips == 16
     with pytest.raises(ValueError):
         plan_placement(64, [PlacementAsk("a", 1, 128)])
+
+
+_PLACEMENT_SCRIPT = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+from repro.configs import reduced
+from repro.core.batching.buckets import Request
+from repro.serving.engine import EngineConfig, build_engine
+from repro.serving.multislice import build_multislice_engine
+
+def reqs():
+    return [Request(rid=9000 + i, arrival=0.0, length=9.0 + 2 * i,
+                    max_new_tokens=3 + i % 4) for i in range(12)]
+
+def devs(tree):
+    return {d for leaf in jax.tree.leaves(tree) for d in leaf.devices()}
+
+cfg = reduced("tinyllama-1.1b")
+ec = EngineConfig(max_new_tokens=6, continuous=True, max_slots=4,
+                  segment_len=3, max_prompt_len=32)
+ms = build_multislice_engine(cfg, n_slices=4, ec=ec)
+assert not ms.replicated
+ms.submit_many(reqs())
+got = {r.rid: np.asarray(r.payload) for r in ms.run_until_idle()}
+seen = set()
+for ps in ms.pod.slices:
+    e = ms.engines[ps.slice_id]
+    own = set(ps.devices.reshape(-1))
+    assert len(own) == 1 and devs(e.params) == own, (ps.slice_id, devs(e.params))
+    assert e.stats["admitted"] > 0, ps.slice_id
+    assert devs(e._pool) == own, (ps.slice_id, devs(e._pool))
+    seen |= own
+assert len(seen) == 4, seen
+one = build_engine(cfg, ec=ec)
+one.submit_many(reqs())
+want = {r.rid: np.asarray(r.payload) for r in one.run_until_idle()}
+assert got.keys() == want.keys()
+for rid in want:
+    np.testing.assert_array_equal(got[rid], want[rid])
+print("PLACEMENT_OK")
+"""
+
+
+def test_slices_place_params_and_pool_on_own_devices():
+    """One slice per device (4 forced host devices, in a subprocess so the
+    device count stays out of this process): each slice engine's params and
+    slot pool live on its own device, and outputs are bit-identical to a
+    one-slice engine. The CPU rehearsal of chip_smoke.py --four-chips."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT_SCRIPT], env=env,
+        capture_output=True, text=True, timeout=420,
+    )
+    assert "PLACEMENT_OK" in out.stdout, out.stdout[-2000:] + out.stderr[-2000:]

@@ -56,6 +56,30 @@ def _check(done, ref):
         np.testing.assert_array_equal(np.asarray(r.payload), ref[r.rid])
 
 
+def _fail_on_shed(svc):
+    """A launch that raised sheds its group and leaves busy() true until
+    take_failed(): surface it as a test failure instead of a hang."""
+    failed = svc.take_failed()
+    if failed:
+        pytest.fail(f"DPU launch shed rids {[r.rid for r in failed]}: "
+                    f"{svc.last_error!r}")
+
+
+def _drain_virtual(svc, max_steps=1000):
+    """Step a virtual-clock service until idle (bounded), returning its
+    outputs in completion order; a shed group fails the test."""
+    now, out = 0.0, []
+    for _ in range(max_steps):
+        if not svc.busy():
+            return out
+        svc.step(now)
+        out.extend(svc.poll(now))
+        _fail_on_shed(svc)
+        nxt = svc.next_ready()
+        now = nxt if nxt is not None else now
+    pytest.fail(f"DPU service still busy after {max_steps} steps")
+
+
 # ---------------------------------------------------------------------------
 # DoubleBuffer + DpuService
 # ---------------------------------------------------------------------------
@@ -89,13 +113,7 @@ def test_dpu_service_virtual_groups_and_matches_reference():
                 for i, x in enumerate(xs)]
         for r in reqs:
             assert svc.submit(r)
-        now, out = 0.0, []
-        while svc.busy():
-            svc.step(now)
-            out.extend(svc.poll(now))
-            nxt = svc.next_ready()
-            now = nxt if nxt is not None else now
-        return svc, out
+        return svc, _drain_virtual(svc)
 
     svc, out = run()
     assert svc.stats["groups"] == 2          # one 48000-stack + one 32000
@@ -122,6 +140,7 @@ def test_dpu_service_wall_worker_matches_reference():
     while svc.busy() and time.monotonic() - t0 < 60:
         svc.step(time.monotonic())
         done.extend(svc.poll(time.monotonic()))
+        _fail_on_shed(svc)
         time.sleep(0.001)
     svc.close()
     assert len(done) == 4
@@ -143,12 +162,7 @@ def test_dpu_service_fused_pallas_launch():
             for i, x in enumerate(xs)]
     for r in reqs:
         assert svc.submit(r)
-    now, out = 0.0, []
-    while svc.busy():
-        svc.step(now)
-        out.extend(svc.poll(now))
-        nxt = svc.next_ready()
-        now = nxt if nxt is not None else now
+    out = _drain_virtual(svc)
     assert len(out) == 3 and svc.stats["groups"] == 1  # one padded launch
     for r in sorted(out, key=lambda r: r.rid):
         np.testing.assert_allclose(r.payload, pp.audio_pipeline(xs[r.rid]),
@@ -174,12 +188,7 @@ def test_dpu_service_fused_image_launch():
             for i, c in enumerate(cos)]
     for r in reqs:
         assert svc.submit(r)
-    now, out = 0.0, []
-    while svc.busy():
-        svc.step(now)
-        out.extend(svc.poll(now))
-        nxt = svc.next_ready()
-        now = nxt if nxt is not None else now
+    out = _drain_virtual(svc)
     assert len(out) == 3 and svc.stats["groups"] == 1  # one padded launch
     for r in sorted(out, key=lambda r: r.rid):
         np.testing.assert_allclose(r.payload, pp.image_pipeline(cos[r.rid], qt),
@@ -195,12 +204,7 @@ def test_dpu_service_fused_image_launch():
              for i in range(2)]
     for r in reqs2:
         assert svc2.submit(r)
-    now, out2 = 0.0, []
-    while svc2.busy():
-        svc2.step(now)
-        out2.extend(svc2.poll(now))
-        nxt = svc2.next_ready()
-        now = nxt if nxt is not None else now
+    out2 = _drain_virtual(svc2)
     for r in sorted(out2, key=lambda r: r.rid):
         np.testing.assert_allclose(
             r.payload, pp.image_pipeline(cos[r.rid], qts[r.rid]),

@@ -102,3 +102,18 @@ def test_serving_engine_end_to_end():
     done = engine.run_until_idle()
     assert len(done) == 8
     assert all(r.payload is not None and len(r.payload) == 4 for r in done)
+
+
+@pytest.mark.parametrize("kind,known", [("TPU v5 lite", True),
+                                        ("cpu", False), ("TPU v4", False)])
+def test_peaks_keyed_by_device_kind(kind, known):
+    """Peak rates come from a table keyed by device_kind; a kind without a
+    published entry raises instead of borrowing another chip's numbers."""
+    from repro.analysis import roofline
+
+    if known:
+        p = roofline.peaks_for(kind)
+        assert (p.flops, p.hbm_bw) == (197e12, 819e9)
+    else:
+        with pytest.raises(ValueError, match="no published peaks"):
+            roofline.peaks_for(kind)

@@ -417,7 +417,7 @@ except ImportError:  # pragma: no cover - exercised only without hypothesis
 @_paced
 @_seeded
 def test_registry_reconciles_with_conservation_under_chaos(
-        cfg, params, seed=0):
+        cfg, params, seed):
     from repro.core.dpu.service import DpuService, DpuServiceConfig
     from repro.serving.faults import FaultPlan, replay_virtual
     from repro.serving.runtime import RuntimeConfig, build_pipelined_runtime
