@@ -47,7 +47,7 @@ preprocessing is actual work on the serving path. CPU-inline preprocessing
 preprocessing wall) is compared against the stage-pipelined runtime
 (serving/runtime.py) with a decoupled DpuService overlapping preprocessing
 with decode; outputs must be bit-identical, and per-stage queue-depth /
-occupancy telemetry is recorded.
+DPU busy-share telemetry is recorded.
 
 Part 5 — chunked prefill + streaming (PR 5): a heavy-tailed prompt-length
 Poisson trace through the same slice pool under the old batch-granularity
@@ -105,6 +105,7 @@ from repro.serving.engine import EngineConfig, ServingEngine, build_engine
 from repro.serving.multislice import MultiSliceEngine, build_multislice_engine
 from repro.serving.requests import Phase, WorkloadSpec, generate_requests
 from repro.serving.runtime import PipelinedRuntime, RuntimeConfig
+from repro.serving import telemetry as tm
 
 ARCH = "tinyllama-1.1b"
 MAX_NEW_TOKENS = 32     # SERVE_MODELS decode_steps for the text LM
@@ -852,7 +853,7 @@ def bench_prefix_cache(cfg, trace_n: int, mean_gap_s: float) -> dict:
 #
 # Outputs must be bit-identical per request (the runtime changes when work
 # happens, never what is computed); the section records per-stage queue
-# depth / occupancy telemetry and the per-slice compile-once invariant.
+# depth, the DPU busy share and the per-slice compile-once invariant.
 
 PREPROCESS_SAMPLES = 192000   # 12 s audio @16k: a real preprocessing wall
 OVERLAP_SLICES = 2
@@ -980,7 +981,9 @@ def bench_preprocess_overlap(cfg, trace_n: int, mean_gap_s: float) -> dict:
     pipe_res = _overlap_metrics(
         engine, engine.completed, reqs, makespan, tb, engine.trace_counts())
     pipe_res["stage_queue_depth"] = rt.stage_summary()
-    pipe_res["stage_occupancy"] = rt.stage_occupancy()
+    # the DPU's busy share: its launches (dpu_exec spans) over the replay
+    pipe_res["dpu_busy_share"] = round(sum(
+        e.dur for e in rt.tracer.of(tm.DPU_EXEC)) / makespan, 4)
     pipe_res["shed"] = len(rt.shed)
     pipe_res["shed_reasons"] = rt.shed_counts()
     pipe_res["dead_reasons"] = rt.dead_counts()

@@ -44,6 +44,10 @@ class Request:
     # hedge clones share the (read-only) array.
     prompt: Any = None
     preprocessed_at: Optional[float] = None
+    # when the DPU handed the request back (the worker's finish, or the
+    # modeled completion on the virtual clock); preprocessed_at is the
+    # later harvest into the ready queue
+    preprocess_finished_at: Optional[float] = None
     dispatched_at: Optional[float] = None
     # TTFT telemetry: when the request's FIRST output token materialized
     # (prefill/final-chunk greedy token on the slot-pool path; batch finish

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import heapq
 import threading
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional, Tuple
@@ -193,17 +194,6 @@ class DpuService:
             return sum(len(g) for g in self._work) + self._inflight \
                 + len(self._done)
 
-    def executing(self) -> int:
-        """Requests launched on (or queued to) the CU pool right now —
-        excludes completed work awaiting harvest. This is the occupancy
-        telemetry signal: under backpressure the input queue can be full
-        while the CUs sit idle, and busy() would misreport that as DPU
-        work."""
-        if self.cfg.clock == "virtual":
-            return len(self._scheduled)
-        with self._cond:
-            return sum(len(g) for g in self._work) + self._inflight
-
     def ready(self) -> int:
         return len(self._ready)
 
@@ -302,19 +292,30 @@ class DpuService:
 
             from repro.kernels import ops as kops
 
+            # phases of the enclosing dpu_exec span (a no-op outside one):
+            # stack + transfer, the device's run, the copy back
+            sp = self.tracer.current()
+            sp.phase("stage")
             if self.cfg.dpu.modality == "audio":
-                out = np.asarray(kops.audio_pipeline_batch(jnp.stack(xs)))
+                x = jnp.stack(xs)
+                sp.phase("device")
+                y = kops.audio_pipeline_batch(x).block_until_ready()
+                sp.phase("readback")
+                out = np.asarray(y)
                 self.dpu.processed += n
                 return [out[i] for i in range(n)]
             qt = np.asarray(xs[0]["qtable"])
             if all(np.array_equal(np.asarray(x["qtable"]), qt) for x in xs[1:]):
-                out = np.asarray(kops.image_pipeline_batch(
-                    jnp.stack([jnp.asarray(x["coeffs"]) for x in xs]),
-                    jnp.asarray(qt),
-                ))
+                x = jnp.stack([jnp.asarray(p["coeffs"]) for p in xs])
+                sp.phase("device")
+                y = kops.image_pipeline_batch(
+                    x, jnp.asarray(qt)).block_until_ready()
+                sp.phase("readback")
+                out = np.asarray(y)
                 self.dpu.processed += n
                 return [out[i] for i in range(n)]
             # mixed qtables: per-FU batched path below still shares launches
+            sp.phase("device")
         outs = self.dpu.process_batch(xs)[:n]
         self.dpu.processed -= len(xs) - n  # padded rows are not requests
         return outs
@@ -377,10 +378,13 @@ class DpuService:
                                       rids=[r.rid for r in group])
                     did = True
                     continue
+                self.tracer.span(tm.DPU_EXEC, now, max(ts),
+                                 rids=[r.rid for r in group])
                 for r, t, y in zip(group, ts, outs):
                     heapq.heappush(self._scheduled, (t, self._seq, r))
                     self._seq += 1
                     r.payload = y
+                    r.preprocess_finished_at = t
             else:
                 with self._cond:
                     self._work.append(group)
@@ -402,7 +406,8 @@ class DpuService:
                 heapq.heappop(self._scheduled)
                 self.stats["processed"] += 1
                 self.tracer.event(tm.PREPROCESS_DONE, ready_at, rid=r.rid,
-                                  tenant=getattr(r, "model", None))
+                                  tenant=getattr(r, "model", None),
+                                  finished=r.preprocess_finished_at)
                 did = True
         else:
             with self._cond:
@@ -416,7 +421,8 @@ class DpuService:
                 done.popleft()
                 self.stats["processed"] += 1
                 self.tracer.event(tm.PREPROCESS_DONE, now, rid=r.rid,
-                                  tenant=getattr(r, "model", None))
+                                  tenant=getattr(r, "model", None),
+                                  finished=r.preprocess_finished_at)
                 did = True
             if done:  # ready buffer full: keep the rest for the next step
                 with self._cond:
@@ -442,9 +448,14 @@ class DpuService:
                 group = self._work.popleft()
                 self._inflight += len(group)
             try:
-                outs = self._process_group(group)
+                # the span ends once the outputs are back on the host; its
+                # end is each request's finish, stamped before the hand-back
+                with self.tracer.timed(tm.DPU_EXEC, time.monotonic,
+                                       rids=[r.rid for r in group]) as sp:
+                    outs = self._process_group(group)
                 for r, y in zip(group, outs):
                     r.payload = y
+                    r.preprocess_finished_at = sp.t1
             except Exception as e:  # shed the group, keep serving
                 with self._cond:
                     self.last_error = e

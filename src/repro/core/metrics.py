@@ -25,10 +25,10 @@ three separate call sites). Counters created with `persistent=True`
 NOT evict) are exempt and must be diffed by readers, exactly as the bench
 harness already does.
 
-Exporters: `snapshot()` (JSON), `prometheus_text()` (text exposition), and
-`lint()` (name-uniqueness / label-schema check, also run by CI over the
-exported snapshot). All exports are deterministically ordered so a
-virtual-clock replay exports byte-identical artifacts.
+Exporters: `snapshot()` (JSON) and `lint()` (name-uniqueness /
+label-schema check, also run by CI over the exported snapshot). All
+exports are deterministically ordered so a virtual-clock replay exports
+byte-identical artifacts.
 """
 from __future__ import annotations
 
@@ -289,31 +289,6 @@ class MetricsRegistry:
 
     def to_json(self) -> str:
         return json.dumps(self.snapshot(), sort_keys=True, indent=2)
-
-    def prometheus_text(self) -> str:
-        lines = []
-        seen_type = set()
-        for r in self._rows():
-            kind = r["kind"]
-            if r["name"] not in seen_type:
-                seen_type.add(r["name"])
-                lines.append(f"# TYPE {r['name']} "
-                             f"{'histogram' if kind == 'histogram' else kind}")
-            lab = ",".join(f'{k}="{v}"'
-                           for k, v in sorted(r["labels"].items()))
-            lab = "{" + lab + "}" if lab else ""
-            if kind == "histogram":
-                lines.append(f"{r['name']}_count{lab} {r['count']}")
-                lines.append(f"{r['name']}_sum{lab} {r['sum']}")
-                for q in (0.5, 0.95, 0.99):
-                    v = r[f"p{int(q * 100)}"]
-                    if v is not None:
-                        qlab = (lab[:-1] + "," if lab else "{") \
-                            + f'quantile="{q}"' + "}"
-                        lines.append(f"{r['name']}{qlab} {v}")
-            else:
-                lines.append(f"{r['name']}{lab} {r['value']}")
-        return "\n".join(lines) + "\n"
 
     def lint(self) -> List[str]:
         """Schema check across self + children: a metric name must map to

@@ -292,6 +292,7 @@ def main():
 
     if args.pipelined:
         from repro.core.dpu.service import DpuService, DpuServiceConfig
+        from repro.serving import telemetry as tm
         from repro.serving.runtime import RuntimeConfig, build_pipelined_runtime
 
         import time
@@ -363,9 +364,9 @@ def main():
               f"last_error={service.last_error if service else None!r}")
         for stage, st in rt.stage_summary().items():
             print(f"  queue[{stage}]: mean={st['mean']:.2f} max={st['max']}")
-        occ = rt.stage_occupancy()
-        print(f"  occupancy: preprocess={occ['preprocess']:.3f} "
-              f"slots={occ['slots']:.3f}")
+        launches = rt.tracer.of(tm.DPU_EXEC)
+        print(f"  dpu_exec: {len(launches)} launches, "
+              f"{sum(e.dur for e in launches):.3f} s")
         if controller is not None:
             print(f"  controller: {len(controller.decisions)} "
                   f"reconfiguration(s), fleet now "

@@ -285,6 +285,9 @@ class ServingEngine:
             "prefix_prompt_tokens",
             "prefix_inserts",
             "prefix_scatter_traces",
+            # output tokens appended to slot rows (first tokens at admit and
+            # final chunk, segment tokens within budget, up to an EOS)
+            "tokens_served",
         ), labels=self._labels, persistent=self._PERSISTENT_STATS)
         self._h_exec = self.registry.histogram(
             "engine_batch_exec_seconds", self._labels)
@@ -530,6 +533,11 @@ class ServingEngine:
             return now
         return time.monotonic()
 
+    def _timed(self, kind: str, now: Optional[float], **fields):
+        """An open tracer span of this engine, timed on `_stamp`'s clock."""
+        return self.tracer.timed(kind, lambda: self._stamp(now),
+                                 sid=self._sid, tenant=self._tenant, **fields)
+
     def _reset_state(self) -> None:
         """Registry reset hook: clear Python-side accumulators alongside
         the counters so no signal survives the warmup boundary unpaired.
@@ -701,37 +709,48 @@ class ServingEngine:
 
     def _admit(self, reqs: List[Request],
                now: Optional[float] = None) -> None:
-        """Prefill a left-padded admission group and join it into free slots."""
-        self._ensure_pool()
-        free = [i for i, s in enumerate(self._slots) if s is None]
-        assert len(reqs) <= len(free), (len(reqs), len(free))
-        lens = [max(1, int(r.length)) for r in reqs]
-        lp = max(self.ec.min_prompt_len, _next_pow2(max(lens)))
-        assert lp <= self.ec.max_prompt_len, lp  # enforced at submit time
-        assert self._clock >= lp  # clock starts at max_prompt_len, only grows
-        bp = self.ec.max_slots
-        toks, off = self._left_pad_prompts(reqs, lens, bp, lp)
-        sids = np.full(bp, bp, np.int32)  # out-of-range rows -> dropped
-        sids[: len(reqs)] = free[: len(reqs)]
-        tok0, self._pool = self._get_admit(lp)(
-            self.params, self._put(toks), self._put(off), self._pool,
-            self._put(sids), self._put(np.int32(self._clock)),
-        )
-        tok0 = np.asarray(tok0)
-        t = self._stamp(now)
-        for i, r in enumerate(reqs):
-            s = free[i]
-            self._pool_off[s] = self._clock - lens[i]
-            self._tok[s] = tok0[i]
-            self._slots[s] = _Slot(req=r, budget=self._budget(r),
-                                   produced=[int(tok0[i, 0])])
-            r.dispatched_at = t
-            r.first_token_at = t  # TTFT: prefill emits the first token
-            self.stats["prefix_prompt_tokens"] += lens[i]
-        self.stats["admitted"] += len(reqs)
-        self.tracer.event(tm.ADMIT, t, sid=self._sid, tenant=self._tenant,
-                          bucket=lp, rids=[r.rid for r in reqs])
-        self._retire_finished(t)  # budget-1 / instant-EOS requests
+        """Prefill a left-padded admission group and join it into free
+        slots. Its `admission` span runs from the group's admission start:
+        `prepare` (host padding up to the dispatch), `wait` (to the
+        first tokens on the host), `host` (stamping, retiring)."""
+        with self._timed(tm.ADMISSION, now, rids=[r.rid for r in reqs],
+                         rows=len(reqs)) as sp:
+            sp.phase("prepare")
+            self._ensure_pool()
+            free = [i for i, s in enumerate(self._slots) if s is None]
+            assert len(reqs) <= len(free), (len(reqs), len(free))
+            lens = [max(1, int(r.length)) for r in reqs]
+            lp = max(self.ec.min_prompt_len, _next_pow2(max(lens)))
+            assert lp <= self.ec.max_prompt_len, lp  # enforced at submit
+            assert self._clock >= lp  # starts at max_prompt_len, only grows
+            sp.set(bucket=lp)
+            bp = self.ec.max_slots
+            toks, off = self._left_pad_prompts(reqs, lens, bp, lp)
+            sids = np.full(bp, bp, np.int32)  # out-of-range rows -> dropped
+            sids[: len(reqs)] = free[: len(reqs)]
+            tok0, self._pool = self._get_admit(lp)(
+                self.params, self._put(toks), self._put(off), self._pool,
+                self._put(sids), self._put(np.int32(self._clock)),
+            )
+            sp.phase("wait")
+            tok0 = np.asarray(tok0)
+            sp.phase("host")
+            t = self._stamp(now)
+            for i, r in enumerate(reqs):
+                s = free[i]
+                self._pool_off[s] = self._clock - lens[i]
+                self._tok[s] = tok0[i]
+                self._slots[s] = _Slot(req=r, budget=self._budget(r),
+                                       produced=[int(tok0[i, 0])])
+                r.dispatched_at = t
+                r.first_token_at = t  # TTFT: prefill emits the first token
+                self.stats["prefix_prompt_tokens"] += lens[i]
+            self.stats["admitted"] += len(reqs)
+            self.stats["tokens_served"] += len(reqs)
+            self.tracer.event(tm.ADMIT, t, sid=self._sid,
+                              tenant=self._tenant, bucket=lp,
+                              rids=[r.rid for r in reqs])
+            self._retire_finished(t)  # budget-1 / instant-EOS requests
 
     # --- chunked prefill ----------------------------------------------------
     def _pick_chunk(self, lp: int) -> int:
@@ -790,7 +809,17 @@ class ServingEngine:
         to produce the first token at column lp-1) and m ≡ n (mod chunk)
         (off = lp - n, lp ≡ 0 mod chunk, so the resume column lands on the
         chunk grid). The group splits into one _ChunkAdmission per resume
-        column; same-class admissions still merge into one call per step."""
+        column; same-class admissions still merge into one call per step.
+
+        Its `admission` span has one phase, `prepare`: the chunks and their
+        sync come in later steps."""
+        with self._timed(tm.ADMISSION, now, rids=[r.rid for r in reqs],
+                         rows=len(reqs), bucket=lp) as sp:
+            sp.phase("prepare")
+            self._queue_chunked(reqs, lp, chunk, now)
+
+    def _queue_chunked(self, reqs: List[Request], lp: int, chunk: int,
+                       now: Optional[float]) -> None:
         self._ensure_pool()
         free = [i for i, s in enumerate(self._slots) if s is None]
         assert len(reqs) <= len(free), (len(reqs), len(free))
@@ -961,29 +990,37 @@ class ServingEngine:
                     now: Optional[float] = None) -> None:
         """Run one chunk for every admission of a (chunk, bucket) class in
         ONE program call (per-row start); admissions reaching their final
-        chunk flip their rows live (decode starts at the next segment)."""
-        t0 = time.monotonic()
-        bp = self.ec.max_slots
-        toks = np.zeros((bp, c), np.int32)
-        off = np.full(bp, lp, np.int32)   # sentinel: rows not ours, masked
-        start = np.zeros(bp, np.int32)
-        for adm in adms:
-            for s in adm.slots:
-                col = adm.base + adm.pos  # prefix hits resume past base
-                toks[s] = adm.toks[s, col:col + c]
-                off[s] = adm.off[s]
-                start[s] = col
-        tok0, self._pool = self._get_chunk(c, lp)(
-            self.params, self._put(toks), self._put(off), self._pool,
-            self._put(start),
-        )
-        exec_s = time.monotonic() - t0
+        chunk flip their rows live (decode starts at the next segment).
+
+        No sync precedes the final chunk's, so the `chunk_dispatch` span,
+        PREFILL_CHUNK's `dur` and this call's exec time all measure the
+        host's preparation and dispatch; the chunk's device time is read
+        from a profile."""
+        rids = [r.rid for a in adms for r in a.reqs]
+        cols = [a.base + a.pos for a in adms]  # prefix hits resume past base
+        with self._timed(tm.CHUNK_DISPATCH, now, bucket=lp, chunk=c,
+                         rids=rids, cols=cols):
+            t0 = time.monotonic()
+            bp = self.ec.max_slots
+            toks = np.zeros((bp, c), np.int32)
+            off = np.full(bp, lp, np.int32)   # sentinel: not ours, masked
+            start = np.zeros(bp, np.int32)
+            for adm, col in zip(adms, cols):
+                for s in adm.slots:
+                    toks[s] = adm.toks[s, col:col + c]
+                    off[s] = adm.off[s]
+                    start[s] = col
+            tok0, self._pool = self._get_chunk(c, lp)(
+                self.params, self._put(toks), self._put(off), self._pool,
+                self._put(start),
+            )
+            exec_s = time.monotonic() - t0
         self.batch_exec_s.append(exec_s)
         self._h_exec.observe(exec_s)
         self.tracer.event(
             tm.PREFILL_CHUNK, self._stamp(now), sid=self._sid,
             tenant=self._tenant, bucket=lp, chunk=c,
-            rows=sum(len(a.slots) for a in adms),
+            rows=sum(len(a.slots) for a in adms), rids=rids,
             dur=None if self._virtual else exec_s)
         finished: List[_ChunkAdmission] = []
         for adm in adms:
@@ -1010,48 +1047,66 @@ class ServingEngine:
                 if s not in adm.stamped:  # scatter-stamped rows keep theirs
                     st.req.first_token_at = t  # TTFT: final chunk greedy tok
             self.stats["admitted"] += len(adm.reqs)
+            self.stats["tokens_served"] += len(adm.slots)
         self._retire_finished(t)
 
     def _decode_segment(self, steps: int,
                         now: Optional[float] = None) -> None:
-        """One fused segment over the whole pool; finished rows retire after."""
-        # mid-prefill rows: pin the (ignored) segment write to ring slot
-        # `filled` — at or above the written prefix, below the pool ring —
-        # so interleaved garbage can never land on real prompt KV and stays
-        # behind the causal mask until a later chunk/decode overwrites it
-        for s, st in enumerate(self._slots):
-            if st is not None and not st.live:
-                self._pool_off[s] = self._clock - st.filled
-        t0 = time.monotonic()
-        toks, self._pool = self._segment_jit(
-            self.params, self._pool, self._put(self._tok),
-            self._put(np.int32(self._clock)), self._put(self._pool_off),
-            int(steps),
-        )
-        toks = np.asarray(toks)
-        self._clock += steps
-        if self._clock >= self.ec.max_prompt_len + 8 * self.pool_len:
-            self._rebase_clock()
-        self._tok = toks[:, -1:].astype(np.int32).copy()
-        done = time.monotonic()
-        exec_s = done - t0
-        self.batch_exec_s.append(exec_s)
-        self._h_exec.observe(exec_s)
-        self.stats["segments"] += 1
-        n_active = self.ec.max_slots - self._free_slots()
-        self._h_occ.observe(n_active / self.ec.max_slots)
-        stamp = now if (self._virtual and now is not None) else done
-        self.tracer.event(
-            tm.DECODE_SEGMENT, stamp, sid=self._sid, tenant=self._tenant,
-            steps=int(steps), active=n_active,
-            dur=None if self._virtual else exec_s)
-        for s, st in enumerate(self._slots):
-            if st is None or not st.live:
-                continue  # mid-prefill rows produce nothing yet
-            take = min(steps, st.budget - len(st.produced))
-            if take > 0:
-                st.produced.extend(int(t) for t in toks[s, :take])
-        self._retire_finished(stamp)
+        """One fused segment over the whole pool; finished rows retire
+        after. Its `segment` span has the phases `prepare` (up to the
+        dispatch), `wait` (to the tokens on the host) and `host` (token
+        bookkeeping, retiring), and carries `live` (rows that decode) and
+        `tokens` (tokens appended), as DECODE_SEGMENT does."""
+        live = sum(1 for st in self._slots if st is not None and st.live)
+        with self._timed(tm.SEGMENT, now, steps=int(steps), live=live) as sp:
+            sp.phase("prepare")
+            # mid-prefill rows: pin the (ignored) segment write to ring slot
+            # `filled` — at or above the written prefix, below the pool ring
+            # — so interleaved garbage can never land on real prompt KV and
+            # stays behind the causal mask until a later chunk/decode
+            # overwrites it
+            for s, st in enumerate(self._slots):
+                if st is not None and not st.live:
+                    self._pool_off[s] = self._clock - st.filled
+            t0 = time.monotonic()
+            toks, self._pool = self._segment_jit(
+                self.params, self._pool, self._put(self._tok),
+                self._put(np.int32(self._clock)), self._put(self._pool_off),
+                int(steps),
+            )
+            sp.phase("wait")
+            toks = np.asarray(toks)
+            sp.phase("host")
+            self._clock += steps
+            if self._clock >= self.ec.max_prompt_len + 8 * self.pool_len:
+                self._rebase_clock()
+            self._tok = toks[:, -1:].astype(np.int32).copy()
+            done = time.monotonic()
+            exec_s = done - t0
+            self.batch_exec_s.append(exec_s)
+            self._h_exec.observe(exec_s)
+            self.stats["segments"] += 1
+            n_active = self.ec.max_slots - self._free_slots()
+            self._h_occ.observe(n_active / self.ec.max_slots)
+            stamp = now if (self._virtual and now is not None) else done
+            eos = self.ec.eos_id
+            served = 0
+            for s, st in enumerate(self._slots):
+                if st is None or not st.live:
+                    continue  # mid-prefill rows produce nothing yet
+                take = min(steps, st.budget - len(st.produced))
+                if take > 0:
+                    new = [int(t) for t in toks[s, :take]]
+                    st.produced.extend(new)
+                    # tokens past an EOS are cut at retire: not served
+                    served += new.index(eos) + 1 if eos in new else take
+            self.stats["tokens_served"] += served
+            sp.set(tokens=served)
+            self.tracer.event(
+                tm.DECODE_SEGMENT, stamp, sid=self._sid, tenant=self._tenant,
+                steps=int(steps), active=n_active, live=live, tokens=served,
+                dur=None if self._virtual else exec_s)
+            self._retire_finished(stamp)
 
     def _rebase_clock(self) -> None:
         """Shift the clock and every slot offset down by a multiple of the

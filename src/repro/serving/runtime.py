@@ -197,8 +197,6 @@ class PipelinedRuntime:
                                        labels={"stage": k})
             for k in _STAGES
         }
-        # DPU occupancy samples (0/1)
-        self._pre_busy = self.registry.histogram("runtime_dpu_busy")
         # optional online partition controller (core/control/partition.py):
         # observes front-door arrivals and is polled once per step(); when
         # its hysteresis + cost model clear, it drives engine.resize()
@@ -685,12 +683,9 @@ class PipelinedRuntime:
                 self.service.pending() + self.service.in_flight()
             )
             self._depths["ready"].observe(self.service.ready())
-            # occupancy counts actual CU execution, not queued-but-idle
-            self._pre_busy.observe(int(self.service.executing() > 0))
         else:
             self._depths["preprocess"].observe(0)
             self._depths["ready"].observe(0)
-            self._pre_busy.observe(0)
         self._depths["admission"].observe(self.engine.admission_depth())
         self._depths["slots"].observe(self.engine.slots_in_use())
 
@@ -701,16 +696,6 @@ class PipelinedRuntime:
             k: {"mean": round(st.mean, 3),
                 "max": int(st.vmax) if st.count else 0}
             for k, st in self._depths.items()
-        }
-
-    def stage_occupancy(self) -> Dict[str, float]:
-        """Fraction-of-time-busy per resource stage: the DPU (service busy
-        across step samples) and the KV slot pools (occupied fraction)."""
-        cap = self.engine.slot_capacity()
-        slots = self._depths["slots"]
-        return {
-            "preprocess": round(self._pre_busy.mean, 3),
-            "slots": round(slots.mean / cap, 3) if cap else 0.0,
         }
 
     def _reset_state(self) -> None:

@@ -7,7 +7,11 @@ Every serving layer stamps typed span events onto one shared `Tracer`:
     retire | shed | dead_letter
 
 plus the fleet-health transitions (hedge, requeue, quarantine, readmit,
-resize, fault, breaker_trip/breaker_close, cpu_fallback). Events carry the
+resize, fault, breaker_trip/breaker_close, cpu_fallback), and timed spans
+of the work itself (`Tracer.span` / `Tracer.timed`): the DPU worker's
+launch of a group (dpu_exec), an engine admission (admission), a chunk's
+dispatch (chunk_dispatch) and a decode segment (segment), each split into
+phases recorded as child spans `<kind>.<phase>`. Events carry the
 (tenant, slice, bucket) labels of the issue plus the request id and an
 open extras dict; timestamps are the CALLER's clock, so on the virtual
 clock the whole timeline is a deterministic pure function of trace + fault
@@ -23,7 +27,10 @@ limit — it is a telemetry stream, not a log.
 from __future__ import annotations
 
 import json
-from typing import List, Optional
+import threading
+from typing import Callable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # -- span kinds (the typed lifecycle vocabulary) ----------------------------
 INGEST = "ingest"
@@ -49,12 +56,20 @@ FAULT = "fault"                    # injector fired a FaultEvent
 BREAKER_TRIP = "breaker_trip"
 BREAKER_CLOSE = "breaker_close"
 CPU_FALLBACK = "cpu_fallback"
+# timed spans (t = start, dur = length); their phases are child spans
+# named "<kind>.<phase>" that carry the parent's seq
+DPU_EXEC = "dpu_exec"              # DPU worker: group popped -> handed back
+ADMISSION = "admission"            # engine admission of a group (one-pass
+#                                    prefill, or a chunked group's set-up)
+CHUNK_DISPATCH = "chunk_dispatch"  # one chunk program's host prep + dispatch
+SEGMENT = "segment"                # one decode segment, dispatch to retire
 
 SPAN_KINDS = (
     INGEST, PREPROCESS_LAUNCH, PREPROCESS_DONE, PREPROCESS_FAIL, OFFER,
     DISPATCH, ADMIT, PREFILL_CHUNK, PREFIX_SCATTER, DECODE_SEGMENT, RETIRE,
     SHED, DEAD_LETTER, HEDGE, REQUEUE, QUARANTINE, READMIT, RESIZE, RECONFIG,
     FAULT, BREAKER_TRIP, BREAKER_CLOSE, CPU_FALLBACK,
+    DPU_EXEC, ADMISSION, CHUNK_DISPATCH, SEGMENT,
 )
 
 
@@ -94,6 +109,79 @@ class SpanEvent:
         return f"SpanEvent({self.to_json()!r})"
 
 
+class Timed:
+    """An open span (`Tracer.timed`). Entering it also enters
+    `jax.profiler.TraceAnnotation("repro.<kind>")`, so the block shows on
+    the profiler's clock in any profile taken meanwhile; `phase(name)`
+    starts a named phase (ending the one before, each with an annotation
+    "repro.<kind>.<name>"). On exit the span is recorded with `fields`
+    (an exception adds `error`), then each phase as a child span
+    "<kind>.<name>" whose `parent` is the span's seq."""
+
+    __slots__ = ("tracer", "kind", "clock", "fields", "t0", "t1",
+                 "_phases", "_ann", "_pann")
+
+    def __init__(self, tracer: "Tracer", kind: str,
+                 clock: Callable[[], float], fields: dict):
+        self.tracer = tracer
+        self.kind = kind
+        self.clock = clock
+        self.fields = fields
+        self.t0 = self.t1 = None
+        self._phases: List[tuple] = []
+        self._pann = None
+
+    def __enter__(self) -> "Timed":
+        self._ann = TraceAnnotation("repro." + self.kind)
+        self._ann.__enter__()
+        tl = self.tracer._open
+        if not hasattr(tl, "stack"):
+            tl.stack = []
+        tl.stack.append(self)
+        self.t0 = self.clock()
+        return self
+
+    def phase(self, name: str) -> None:
+        t = self.clock()
+        if self._pann is not None:
+            self._pann.__exit__(None, None, None)
+        self._phases.append((name, t))
+        self._pann = TraceAnnotation(f"repro.{self.kind}.{name}")
+        self._pann.__enter__()
+
+    def set(self, **extra) -> None:
+        """Add fields known only once the work is done."""
+        self.fields.update(extra)
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self.t1 = self.clock()
+        if self._pann is not None:
+            self._pann.__exit__(None, None, None)
+        self._ann.__exit__(et, ev, tb)
+        self.tracer._open.stack.pop()
+        if et is not None:
+            self.fields["error"] = et.__name__
+        seq = self.tracer.span(self.kind, self.t0, self.t1, **self.fields)
+        ends = [t for _, t in self._phases[1:]] + [self.t1]
+        for (name, a), b in zip(self._phases, ends):
+            self.tracer.span(f"{self.kind}.{name}", a, b, parent=seq,
+                             sid=self.fields.get("sid"))
+        return False
+
+
+class _NoSpan:
+    """Stand-in for `Tracer.current()` outside any span."""
+
+    def phase(self, name: str) -> None:
+        pass
+
+    def set(self, **extra) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
 class Tracer:
     """Bounded, append-only lifecycle event stream shared by every layer
     of one pipeline (the composing layer injects itself via set_tracer)."""
@@ -103,25 +191,57 @@ class Tracer:
         self.events: List[SpanEvent] = []
         self.dropped = 0
         self._seq = 0
+        # the DPU worker thread records its spans beside the event loop
+        self._lock = threading.Lock()
+        self._open = threading.local()   # per-thread stack of open spans
 
     def event(self, kind: str, t: float, *, rid=None, tenant=None, sid=None,
-              bucket=None, dur: Optional[float] = None, **extra) -> None:
-        self._seq += 1
-        if len(self.events) >= self.max_events:
-            self.dropped += 1
-            return
-        self.events.append(SpanEvent(
-            self._seq, float(t), kind, rid=rid, tenant=tenant,
-            sid=None if sid is None else str(sid), bucket=bucket, dur=dur,
-            extra=extra or None))
+              bucket=None, dur: Optional[float] = None, **extra) -> int:
+        """Record one event; returns its sequence number (counted even
+        when the bound drops the event)."""
+        with self._lock:
+            self._seq += 1
+            if len(self.events) >= self.max_events:
+                self.dropped += 1
+                return self._seq
+            self.events.append(SpanEvent(
+                self._seq, float(t), kind, rid=rid, tenant=tenant,
+                sid=None if sid is None else str(sid), bucket=bucket,
+                dur=dur, extra=extra or None))
+            return self._seq
+
+    def span(self, kind: str, t0: float, t1: float, *, rid=None, rids=None,
+             parent: Optional[int] = None, **extra) -> int:
+        """Record the interval [t0, t1] as one event (t = t0, dur = t1 - t0).
+        A span over a group carries the group's `rids`; a phase names its
+        enclosing span's seq as `parent`. Returns the span's seq."""
+        if rids is not None:
+            extra["rids"] = rids
+        if parent is not None:
+            extra["parent"] = parent
+        return self.event(kind, t0, rid=rid, dur=t1 - t0, **extra)
+
+    def timed(self, kind: str, clock: Callable[[], float],
+              **fields) -> "Timed":
+        """Context-manager form of `span`: times the block on `clock`
+        (the caller's clock, so virtual replays stay deterministic)."""
+        return Timed(self, kind, clock, fields)
+
+    def current(self) -> "Timed":
+        """The innermost span the calling thread has open (a no-op stand-in
+        when it has none), so code called inside a span can mark its
+        phases without being handed it."""
+        stack = getattr(self._open, "stack", None)
+        return stack[-1] if stack else _NO_SPAN
 
     def reset(self) -> None:
         """Clear the stream (the registry reset hook calls this at the
         warmup boundary, so exported timelines start at the measured
         window)."""
-        self.events.clear()
-        self.dropped = 0
-        self._seq = 0
+        with self._lock:
+            self.events.clear()
+            self.dropped = 0
+            self._seq = 0
 
     def counts(self) -> dict:
         out: dict = {}

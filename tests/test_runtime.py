@@ -14,6 +14,7 @@ from repro.core.batching.buckets import Request
 from repro.core.dpu.runtime import DpuConfig
 from repro.core.dpu.service import DoubleBuffer, DpuService, DpuServiceConfig
 from repro.data import preprocess_cpu as pp
+from repro.serving import telemetry as tm
 from repro.serving.engine import EngineConfig, build_engine
 from repro.serving.runtime import (
     PipelinedRuntime, RuntimeConfig, build_pipelined_runtime,
@@ -477,6 +478,9 @@ def test_stage_telemetry_shapes(setup):
                            "slots"}
     for st in depths.values():
         assert st["max"] >= st["mean"] >= 0.0
-    occ = rt.stage_occupancy()
-    assert 0.0 <= occ["preprocess"] <= 1.0
-    assert 0.0 <= occ["slots"] <= 1.0
+    # the DPU's busy time is its dpu_exec spans: one per launched group,
+    # covering the six requests once each
+    launches = rt.tracer.of(tm.DPU_EXEC)
+    assert launches and all(e.dur >= 0.0 for e in launches)
+    assert sorted(r for e in launches for r in e.extra["rids"]) \
+        == [6000 + i for i in range(6)]

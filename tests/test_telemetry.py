@@ -156,9 +156,10 @@ def test_exports_deterministic_and_prometheus_shape():
             h.observe(v)
         return reg
     assert build().to_json() == build().to_json()
-    text = build().prometheus_text()
-    assert 'served_total{tenant="a"} 3' in text
-    assert "lat_seconds_count 3" in text
+    rows = {r["name"]: r for r in build().snapshot()["metrics"]}
+    assert rows["served_total"]["labels"] == {"tenant": "a"}
+    assert rows["served_total"]["value"] == 3
+    assert rows["lat_seconds"]["count"] == 3
 
 
 def test_stats_view_is_a_dict_facade():
@@ -211,6 +212,43 @@ def test_tracer_bounded_with_drop_count():
     assert len(tr.events) == 3 and tr.dropped == 2
     tr.reset()
     assert tr.events == [] and tr.dropped == 0
+
+
+def test_tracer_records_from_many_threads_without_losing_events():
+    """The DPU worker records spans beside the event loop: concurrent
+    writers get distinct sequence numbers and no event is lost, and each
+    thread sees only its own open span."""
+    import os
+    import sys
+    import threading
+
+    tr = tm.Tracer()
+    n_threads, per = (os.cpu_count() or 2) + 2, 300
+    seen_other = []
+
+    def work(k):
+        for i in range(per):
+            with tr.timed(tm.DPU_EXEC, lambda: float(i), rids=[k]) as sp:
+                sp.phase("device")
+                seen_other.append(tr.current() is not sp)
+            tr.event(tm.INGEST, float(i), rid=k)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        ts = [threading.Thread(target=work, args=(k,))
+              for k in range(n_threads)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in ts)
+    finally:
+        sys.setswitchinterval(old)
+    total = n_threads * per * 3        # span + phase + point event
+    assert len(tr.events) == total and tr.dropped == 0
+    assert sorted(e.seq for e in tr.events) == list(range(1, total + 1))
+    assert not any(seen_other)
 
 
 def test_span_kind_vocabulary_closed():
@@ -470,3 +508,128 @@ def test_registry_reconciles_with_conservation_under_chaos(
     # completed requests always carry a first token stamp (satellite 2)
     _assert_ttft(done, len(done))
     rt.close()
+
+
+# ---------------------------------------------------------------------------
+# Timed spans: phases, group rids, no events from idle ticks
+# ---------------------------------------------------------------------------
+
+
+def test_tracer_span_and_timed_phases():
+    tr = tm.Tracer()
+    clock = iter([1.0, 1.5, 2.5, 3.0, 4.0]).__next__
+    assert isinstance(tr.current(), type(tm._NO_SPAN))
+    with tr.timed(tm.SEGMENT, clock, sid=0, live=3) as sp:
+        assert tr.current() is sp
+        sp.phase("prepare")            # 1.5
+        sp.phase("wait")               # 2.5
+        sp.phase("host")               # 3.0
+        sp.set(tokens=12)
+    assert tr.current() is not sp      # closed: popped off this thread
+    seg, *phases = tr.events
+    assert (seg.kind, seg.t, seg.dur, seg.sid) == (tm.SEGMENT, 1.0, 3.0, "0")
+    assert seg.extra == {"live": 3, "tokens": 12}
+    assert [(p.kind, p.t, p.dur, p.extra["parent"]) for p in phases] == [
+        ("segment.prepare", 1.5, 1.0, seg.seq),
+        ("segment.wait", 2.5, 0.5, seg.seq),
+        ("segment.host", 3.0, 1.0, seg.seq)]
+    # a group span carries its rids; a failing block is recorded, flagged
+    seq = tr.span(tm.DPU_EXEC, 5.0, 5.25, rids=[3, 4])
+    assert tr.events[-1].extra == {"rids": [3, 4]} and tr.events[-1].seq == seq
+    with pytest.raises(ValueError):
+        with tr.timed(tm.ADMISSION, lambda: 6.0, rids=[5]):
+            raise ValueError("boom")
+    assert tr.events[-1].extra == {"rids": [5], "error": "ValueError"}
+    doc = json.loads(tr.to_json(0.0))
+    x = [e for e in doc["traceEvents"] if e.get("name") == "segment.wait"]
+    assert x[0]["ph"] == "X" and x[0]["dur"] == 0.5e6
+
+
+def _runtime_with_dpu(cfg, params, **ec_kw):
+    from repro.core.dpu.service import DpuService, DpuServiceConfig
+    from repro.serving.runtime import RuntimeConfig, build_pipelined_runtime
+
+    svc = DpuService(DpuServiceConfig(clock="virtual", max_group=2))
+    return build_pipelined_runtime(
+        cfg, ec=_ec(**ec_kw), params=params, service=svc,
+        rc=RuntimeConfig(clock="virtual"))
+
+
+def _audio_reqs(spec, rid0):
+    reqs = _reqs(spec, rid0=rid0)
+    for i, r in enumerate(reqs):
+        r.arrival = 0.01 * i
+        r.payload = np.random.default_rng(i).standard_normal(
+            8000).astype(np.float32)
+    return reqs
+
+
+@pytest.mark.parametrize("ec_kw", [{}, {"chunk_lens": (8,)}],
+                         ids=["one_pass", "chunked"])
+def test_spans_carry_the_rids_they_served(cfg, params, ec_kw):
+    """Each request is in exactly one dpu_exec and one admission span,
+    whose rids are the requests that launch and that admission served;
+    the engine's tokens_served counter and the segments' `tokens` count
+    every token the completed requests carry."""
+    from repro.serving.faults import replay_virtual
+
+    rt = _runtime_with_dpu(cfg, params, **ec_kw)
+    reqs = _audio_reqs([(23, 4), (9, 3), (31, 5), (14, 4), (12, 1)], 7800)
+    done = replay_virtual(rt, reqs)
+    _assert_ttft(done, len(reqs))
+    rids = sorted(r.rid for r in reqs)
+    for kind in (tm.DPU_EXEC, tm.ADMISSION):
+        spans = rt.tracer.of(kind)
+        assert sorted(r for e in spans for r in e.extra["rids"]) == rids
+        assert all(e.dur is not None for e in spans)
+    # a request's finish on the DPU is carried to its PREPROCESS_DONE
+    for e in rt.tracer.of(tm.PREPROCESS_DONE):
+        assert e.extra["finished"] <= e.t
+    # one dpu_exec per launched group: the launch events name the same rids
+    launched = [e.extra["rids"] for e in rt.tracer.of(tm.PREPROCESS_LAUNCH)]
+    assert launched == [e.extra["rids"] for e in rt.tracer.of(tm.DPU_EXEC)]
+    served = sum(len(r.payload) for r in done)
+    assert rt.engine.stats["tokens_served"] == served
+    firsts = len(reqs)
+    assert sum(e.extra["tokens"] for e in rt.tracer.of(tm.SEGMENT)) \
+        == served - firsts
+    segs = rt.tracer.of(tm.DECODE_SEGMENT)
+    assert [e.extra["tokens"] for e in segs] == \
+        [e.extra["tokens"] for e in rt.tracer.of(tm.SEGMENT)]
+    assert all(0 < e.extra["live"] <= e.extra["active"] for e in segs)
+    if ec_kw:
+        chunks = rt.tracer.of(tm.PREFILL_CHUNK)
+        assert chunks and all(e.extra["rids"] for e in chunks)
+        assert [e.extra["rids"] for e in chunks] == \
+            [e.extra["rids"] for e in rt.tracer.of(tm.CHUNK_DISPATCH)]
+    rt.close()
+
+
+def test_idle_steps_record_nothing(cfg, params):
+    rt = _runtime_with_dpu(cfg, params)
+    from repro.serving.faults import replay_virtual
+
+    replay_virtual(rt, _audio_reqs([(9, 3), (12, 2)], 7900))
+    n = len(rt.tracer.events)
+    assert n > 0 and not rt.busy()
+    for i in range(1000):
+        assert not rt.step(10.0 + i * 1e-3)
+    assert len(rt.tracer.events) == n
+    rt.close()
+
+
+def test_virtual_replay_with_spans_exports_byte_identical(cfg, params):
+    from repro.serving.faults import replay_virtual
+
+    def run():
+        rt = _runtime_with_dpu(cfg, params)
+        replay_virtual(rt, _audio_reqs([(23, 4), (9, 3), (31, 5)], 8000))
+        out = rt.tracer.to_json(0.0)
+        rt.close()
+        return out
+
+    a, b = run(), run()
+    assert a == b
+    kinds = {e["name"] for e in json.loads(a)["traceEvents"]}
+    assert {tm.DPU_EXEC, tm.ADMISSION, tm.SEGMENT, "admission.prepare",
+            "segment.wait"} <= kinds
