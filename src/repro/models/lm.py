@@ -436,7 +436,9 @@ def prefill_into_slots(params, tokens, pool_cache, slot_ids, clock,
                        cfg: ModelConfig, *, pos_offset=None):
     """Fused admission: prefill a left-padded (batch, lp) prompt bucket and
     scatter its KV/state into slot-pool rows, one executable per prompt
-    bucket (the compile-once prefill half of continuous batching).
+    bucket (the compile-once prefill half of continuous batching). Row i
+    lands in pool row slot_ids[i]; a slot id >= max_slots marks a padding
+    row, whose write is dropped, so pool rows not named are untouched.
 
     Returns (first greedy tokens [B, 1] int32, new pool cache). The caller
     sets each admitted slot's pos_offset to clock - true_prompt_len so decode
@@ -460,7 +462,8 @@ def prefill_into_slots(params, tokens, pool_cache, slot_ids, clock,
 # The engine interleaves one decode segment between chunks, so resident
 # rows keep producing tokens while a long prompt admits. The chunk program
 # reads and writes only the ring PREFIX [0, lp) (lp = the padded prompt
-# bucket, a static shape): chunk attention costs what the bucket's
+# bucket, a static shape) of the pool rows it is given (slot ids; a fixed
+# row count per program): chunk attention costs what the bucket's
 # monolithic prefill costs — NOT a full-ring scan per chunk — so the
 # executable set is one per (chunk length, prompt bucket), the compile-once
 # bound the engine reports as #chunk buckets + 1 segment. Outputs stay
@@ -488,17 +491,18 @@ def supports_chunked_prefill(cfg: ModelConfig) -> bool:
     )
 
 
-def _attn_chunk(x, p, cfg, cache, qpos, valid, lp: int):
+def _attn_chunk(x, p, cfg, rows, qpos, valid, lp: int):
     """Multi-token cache-extending attention for one prefill chunk.
 
-    x: [B, C, D] chunk hidden states; cache: {'k','v'} slot-pool rows
-    [B, wc, ...]; qpos: [B, C] TRUE positions (negative = left-pad or a row
-    not part of this admission); valid = qpos >= 0; lp: the padded prompt
-    bucket (static). All prompt positions
-    live in the ring PREFIX [0, lp) (ring slot == true position; no wrap:
+    x: [B, C, D] chunk hidden states; rows: {'k','v'} the ring PREFIX
+    [0, lp) of this call's pool rows, [B, lp, ...]; qpos: [B, C] TRUE
+    positions (negative = left-pad or a padding row of the call); valid =
+    qpos >= 0; lp: the padded prompt bucket (static). All prompt positions
+    live in the ring prefix [0, lp) (ring slot == true position; no wrap:
     the ring holds the whole bucket by pool sizing), so only that prefix is
     read, written, and attended — chunk attention costs what the bucket's
-    monolithic prefill costs, not a full-ring scan.
+    monolithic prefill costs, not a full-ring scan. Returns the chunk's
+    output and the updated prefix.
 
     Bit-identity detail — the CANONICAL TRUE-POSITION read contract: the
     attention READ presents the pool rows directly, axis column t = the
@@ -524,12 +528,10 @@ def _attn_chunk(x, p, cfg, cache, qpos, valid, lp: int):
     v1 = jnp.einsum("bsd,dkh->bskh", x, p["wv"].astype(dt))
     q = _rope4(q, qpos, cfg.rope_theta)
     k1 = L.apply_rope(k1, qpos, cfg.rope_theta)
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+    b = jnp.arange(B, dtype=jnp.int32)[:, None]
     slot = jnp.where(valid, qpos, lp)  # invalid -> out of range -> dropped
-    ckp = jax.lax.slice_in_dim(cache["k"], 0, lp, axis=1)
-    cvp = jax.lax.slice_in_dim(cache["v"], 0, lp, axis=1)
-    ckp = ckp.at[rows, slot].set(k1.astype(ckp.dtype), mode="drop")
-    cvp = cvp.at[rows, slot].set(v1.astype(cvp.dtype), mode="drop")
+    ckp = rows["k"].at[b, slot].set(k1.astype(rows["k"].dtype), mode="drop")
+    cvp = rows["v"].at[b, slot].set(v1.astype(rows["v"].dtype), mode="drop")
     # canonical true-position read: axis col t IS true position t; valid up
     # to the row's last query this chunk, stale/future columns masked
     lp_idx = jnp.arange(lp, dtype=jnp.int32)
@@ -541,35 +543,30 @@ def _attn_chunk(x, p, cfg, cache, qpos, valid, lp: int):
     )
     out = jnp.einsum("bskgh,kghd->bsd", o.reshape(B, C, kh, g, hd),
                      p["wo"].astype(dt))
-    ck = jax.lax.dynamic_update_slice_in_dim(cache["k"],
-                                             ckp.astype(cache["k"].dtype), 0, 1)
-    cv = jax.lax.dynamic_update_slice_in_dim(cache["v"],
-                                             cvp.astype(cache["v"].dtype), 0, 1)
-    return out, {"k": ck, "v": cv}
+    return out, {"k": ckp, "v": cvp}
 
 
-def prefill_chunk_into_slots(params, tokens, pool_cache, start,
+def prefill_chunk_into_slots(params, tokens, pool_cache, start, slot_ids,
                              cfg: ModelConfig, *, pos_offset, lp: int):
-    """Run ONE chunk of a left-padded prompt bucket and extend the slot
-    pool's KV in place (see the chunked-prefill module comment above).
+    """Run ONE chunk of a left-padded prompt bucket for the pool rows
+    `slot_ids` and extend their KV in place (see the chunked-prefill module
+    comment above).
 
-    tokens: [max_slots, C] — row b IS pool row b (the engine lays each
-    admitted request's padded prompt on its slot's row); row b's columns
-    are padded prompt positions start[b] .. start[b]+C-1. `start` is PER
-    ROW ([max_slots] int32, traced), so one call advances EVERY in-flight
-    chunked admission of this (C, lp) class at once, each group at its own
-    chunk position — trickled single-request admissions share the pinned
-    program width instead of each paying a full-width call per chunk. lp:
-    the class's padded prompt bucket, a STATIC shape (the executable key
-    is (C, lp); only the ring prefix [0, lp) is read or written).
-    pos_offset: [max_slots] left-pad amounts; rows NOT part of any
-    admission in this class (live decoders, free slots, other buckets'
-    admissions) carry the sentinel offset lp with start 0 (> start + C - 1
-    for every chunk), which makes every column's true position negative:
-    embeddings zeroed, K/V writes dropped, attention fully masked — the
-    chunk program cannot perturb them.
+    tokens: [R, C] — call row i is pool row slot_ids[i]; its columns are
+    padded prompt positions start[i] .. start[i]+C-1. `start` is PER ROW
+    ([R] int32, traced), so one call advances rows of several in-flight
+    chunked admissions of this (C, lp) class at once, each at its own chunk
+    position. lp: the class's padded prompt bucket, a STATIC shape (the
+    executable key is (C, lp)). The program gathers the ring prefix
+    [0, lp) of the call's rows from the pool, runs the chunk over it, and
+    scatters it back with the pool donated: pool rows not in slot_ids are
+    neither read nor written. pos_offset: [R] left-pad amounts. Padding
+    rows of a call carry a slot id >= max_slots (gathered as zeros, their
+    write dropped) and the offset lp with start 0, which makes every
+    column's true position negative: embeddings zeroed, attention fully
+    masked.
 
-    Returns (greedy next token [B, 1] int32 from the LAST column's logits —
+    Returns (greedy next token [R, 1] int32 from the LAST column's logits —
     meaningful only for rows on their bucket's final chunk, where column
     lp-1 is the row's last true prompt position — and the new pool)."""
     if not supports_chunked_prefill(cfg):
@@ -579,6 +576,7 @@ def prefill_chunk_into_slots(params, tokens, pool_cache, start,
         )
     dt = _cdt(cfg)
     C = tokens.shape[1]
+    sids = jnp.asarray(slot_ids, jnp.int32)
     off = pos_offset.astype(jnp.int32)
     qpos = (jnp.asarray(start, jnp.int32)[:, None]
             + jnp.arange(C, dtype=jnp.int32)[None, :]) - off[:, None]
@@ -587,44 +585,48 @@ def prefill_chunk_into_slots(params, tokens, pool_cache, start,
     x = x * valid[..., None].astype(dt)
     pre_kinds, body_kinds = _kinds_for(cfg)
 
-    def sub_step(x, sub, csub):
+    def rows_of(tree, ax):
+        """The call's rows of a cache subtree, ring prefix [0, lp) only
+        (ax: the slot axis, 1 for the stacked body)."""
+        idx = (slice(None),) * ax + (sids, slice(0, lp))
+        return jax.tree.map(
+            lambda c: c.at[idx].get(mode="fill", fill_value=0), tree)
+
+    def put_rows(tree, rows, ax):
+        idx = (slice(None),) * ax + (sids, slice(0, lp))
+        return jax.tree.map(
+            lambda c, r: c.at[idx].set(r.astype(c.dtype), mode="drop"),
+            tree, rows)
+
+    def sub_step(x, sub, rows):
         h = L.rmsnorm(x, sub["mixer"]["ln"], cfg.norm_eps)
-        o, nc = _attn_chunk(h, sub["mixer"], cfg, csub["mixer"], qpos, valid,
-                            lp)
+        o, nrows = _attn_chunk(h, sub["mixer"], cfg, rows, qpos, valid, lp)
         x = x + o
         x, _ = _ffn_forward(x, sub, cfg, ("attn", "mlp"))
-        return x, {"mixer": nc}
+        return x, nrows
 
     new_cache: Dict[str, Any] = {}
     if pre_kinds:
         new_prefix = {}
         for i in range(len(pre_kinds)):
-            x, nc = sub_step(x, params["prefix"][f"l{i}"],
-                             pool_cache["prefix"][f"l{i}"])
-            new_prefix[f"l{i}"] = nc
+            mix = pool_cache["prefix"][f"l{i}"]["mixer"]
+            x, nr = sub_step(x, params["prefix"][f"l{i}"], rows_of(mix, 0))
+            new_prefix[f"l{i}"] = {"mixer": put_rows(mix, nr, 0)}
         new_cache["prefix"] = new_prefix
 
-    nb = jax.tree.leaves(params["body"])[0].shape[0]
+    body = {f"l{li}": pool_cache["body"][f"l{li}"]["mixer"]
+            for li in range(len(body_kinds))}
 
-    def block_fn(carry, xs):
-        x, cbody = carry
-        bp, i = xs
-        cb = jax.tree.map(
-            lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False), cbody
-        )
-        ncb = {}
+    def block_fn(x, xs):
+        bp, rows = xs
+        nrs = {}
         for li in range(len(body_kinds)):
-            x, nc = sub_step(x, bp[f"l{li}"], cb[f"l{li}"])
-            ncb[f"l{li}"] = nc
-        cbody = jax.tree.map(
-            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0), cbody, ncb
-        )
-        return (x, cbody), None
+            x, nrs[f"l{li}"] = sub_step(x, bp[f"l{li}"], rows[f"l{li}"])
+        return x, nrs
 
-    (x, new_body), _ = jax.lax.scan(
-        block_fn, (x, pool_cache["body"]), (params["body"], jnp.arange(nb))
-    )
-    new_cache["body"] = new_body
+    x, new_rows = jax.lax.scan(block_fn, x, (params["body"], rows_of(body, 1)))
+    new_cache["body"] = {k: {"mixer": put_rows(body[k], new_rows[k], 1)}
+                         for k in body}
     x = L.rmsnorm(x, params["final_ln"], cfg.norm_eps)
     logits = logits_from_hidden(params, x[:, -1:], cfg)
     return jnp.argmax(logits, -1).astype(jnp.int32), new_cache

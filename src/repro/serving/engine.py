@@ -18,7 +18,8 @@ Two decode regimes share that prefill discipline:
   slot pool `[max_slots, pool_cache_len]` allocated up front; serving is a
   loop of admit -> decode-segment -> retire. Admission prefills a left-padded
   prompt bucket and scatters it into free row slots (`lm.prefill_into_slots`,
-  one executable per prompt bucket); decode runs `lm.decode_segment`
+  one executable per prompt bucket, `row_tile` rows wide; a larger group
+  runs as several calls of it); decode runs `lm.decode_segment`
   (`segment_len` steps in one jitted scan, pool donated); finished/EOS rows
   free their slots between segments and queued requests join without waiting
   for the pool to drain. A single scalar clock is the shared padded write
@@ -108,6 +109,23 @@ class EngineConfig:
 
 _next_pow2 = next_pow2  # shared shape-bucket formula (buckets.next_pow2)
 
+# Tokens one prefill call computes, summed over its rows, for the call to be
+# compute-bound. A v5e does 197 TFLOP/s against 819 GB/s of HBM, a ridge of
+# ~240 FLOP per byte; a bf16 matmul does 2 FLOP per 2-byte weight per token,
+# so from ~240 tokens a call no longer waits on reading the weights. 512 is
+# about twice that: splitting a group into calls of row_tile rows costs no
+# matmul efficiency, and a call of a few live rows no longer computes the
+# whole pool.
+PREFILL_CALL_TOKENS = 512
+
+
+def row_tile(max_slots: int, row_tokens: int) -> int:
+    """Rows of one prefill call whose rows each contribute `row_tokens`
+    positions (the prompt bucket of a one-pass admission, the chunk length
+    of a chunk): the power of two reaching PREFILL_CALL_TOKENS, capped at
+    the pool. One width per program, so the program set is unchanged."""
+    return min(max_slots, _next_pow2(-(-PREFILL_CALL_TOKENS // row_tokens)))
+
 
 def validate_requests(reqs: List[Request], ec: EngineConfig,
                       *, check_bucket: bool) -> None:
@@ -180,13 +198,13 @@ class _Slot:
 class _ChunkAdmission:
     """One in-flight chunked admission group: a bucket-pure left-padded
     prompt block being written into the pool chunk-by-chunk. `toks`/`off`
-    are laid out on POOL ROWS (row s is slot s; non-member rows carry the
-    sentinel offset lp, which the chunk program fully masks)."""
+    are host arrays laid out on POOL ROWS (row s is slot s; only the rows
+    of `slots` are read)."""
 
     reqs: List[Request]
     slots: List[int]
     toks: np.ndarray         # [max_slots, lp] left-padded prompt tokens
-    off: np.ndarray          # [max_slots] left-pad; lp sentinel = not ours
+    off: np.ndarray          # [max_slots] left-pad of the rows in `slots`
     lp: int
     chunk: int
     pos: int = 0             # next padded column to process (past base)
@@ -288,6 +306,9 @@ class ServingEngine:
             # output tokens appended to slot rows (first tokens at admit and
             # final chunk, segment tokens within budget, up to an EOS)
             "tokens_served",
+            # rows the admit and chunk programs computed (row tile x calls),
+            # padding rows included
+            "prefill_rows_computed",
         ), labels=self._labels, persistent=self._PERSISTENT_STATS)
         self._h_exec = self.registry.histogram(
             "engine_batch_exec_seconds", self._labels)
@@ -429,18 +450,14 @@ class ServingEngine:
         if self.ec.continuous:
             n += self.slot_scheduler.cancel(rids)
             # mid-chunk cancellation: drop the row from its in-flight chunked
-            # admission (masking it via the sentinel offset so later chunk
-            # calls cannot touch its slot); the slot loop below frees and
-            # counts it like any occupied row
+            # admission (later chunk calls name only the admission's slots,
+            # so none touches it); the slot loop below frees and counts it
+            # like any occupied row
             for adm in list(self._chunk_q):
-                keep_r, keep_s = [], []
-                for r, s in zip(adm.reqs, adm.slots):
-                    if r.rid in rids:
-                        adm.off[s] = adm.lp
-                    else:
-                        keep_r.append(r)
-                        keep_s.append(s)
-                adm.reqs, adm.slots = keep_r, keep_s
+                keep = [(r, s) for r, s in zip(adm.reqs, adm.slots)
+                        if r.rid not in rids]
+                adm.reqs = [r for r, _ in keep]
+                adm.slots = [s for _, s in keep]
                 if not adm.reqs:
                     self._chunk_q.remove(adm)
             for s, st in enumerate(self._slots):
@@ -684,13 +701,16 @@ class ServingEngine:
             )
 
     def _put(self, x) -> jax.Array:
-        """Host array -> this engine's devices (pool allocated first)."""
-        return jnp.asarray(x, device=self._dev)
+        """Host array -> this engine's devices (pool allocated first). A
+        plain transfer: unlike `jnp.asarray(x, device=...)`, it compiles no
+        conversion program for each new input shape."""
+        return jax.device_put(x, self._dev)
 
     def _get_admit(self, lp: int):
         """Jitted prefill+admit executable, one per padded prompt length.
-        Admission batch width is pinned to max_slots so the program never
-        retraces as group sizes vary (compile-once over the whole stream)."""
+        Its row width is pinned to row_tile(max_slots, lp) so the program
+        never retraces as group sizes vary (compile-once over the whole
+        stream); a wider group runs as several calls."""
         fn = self._admit_cache.get(lp)
         if fn is not None:
             self.stats["prefill_cache_hits"] += 1
@@ -710,9 +730,11 @@ class ServingEngine:
     def _admit(self, reqs: List[Request],
                now: Optional[float] = None) -> None:
         """Prefill a left-padded admission group and join it into free
-        slots. Its `admission` span runs from the group's admission start:
-        `prepare` (host padding up to the dispatch), `wait` (to the
-        first tokens on the host), `host` (stamping, retiring)."""
+        slots, in calls of the bucket's row tile (all dispatched, the pool
+        chained through them, then one sync). Its `admission` span runs
+        from the group's admission start: `prepare` (host padding up to the
+        last dispatch), `wait` (to the first tokens on the host), `host`
+        (stamping, retiring)."""
         with self._timed(tm.ADMISSION, now, rids=[r.rid for r in reqs],
                          rows=len(reqs)) as sp:
             sp.phase("prepare")
@@ -724,16 +746,24 @@ class ServingEngine:
             assert lp <= self.ec.max_prompt_len, lp  # enforced at submit
             assert self._clock >= lp  # starts at max_prompt_len, only grows
             sp.set(bucket=lp)
-            bp = self.ec.max_slots
-            toks, off = self._left_pad_prompts(reqs, lens, bp, lp)
-            sids = np.full(bp, bp, np.int32)  # out-of-range rows -> dropped
+            rt = row_tile(self.ec.max_slots, lp)
+            n_rows = -(-len(reqs) // rt) * rt
+            toks, off = self._left_pad_prompts(reqs, lens, n_rows, lp)
+            # padding rows name a row past the pool: their write is dropped
+            sids = np.full(n_rows, self.ec.max_slots, np.int32)
             sids[: len(reqs)] = free[: len(reqs)]
-            tok0, self._pool = self._get_admit(lp)(
-                self.params, self._put(toks), self._put(off), self._pool,
-                self._put(sids), self._put(np.int32(self._clock)),
-            )
+            fn = self._get_admit(lp)
+            clock = self._put(np.int32(self._clock))
+            outs = []
+            for i in range(0, n_rows, rt):
+                tok, self._pool = fn(
+                    self.params, self._put(toks[i:i + rt]),
+                    self._put(off[i:i + rt]), self._pool,
+                    self._put(sids[i:i + rt]), clock,
+                )
+                outs.append(tok)
             sp.phase("wait")
-            tok0 = np.asarray(tok0)
+            tok0 = np.concatenate([np.asarray(t) for t in outs])
             sp.phase("host")
             t = self._stamp(now)
             for i, r in enumerate(reqs):
@@ -747,9 +777,11 @@ class ServingEngine:
                 self.stats["prefix_prompt_tokens"] += lens[i]
             self.stats["admitted"] += len(reqs)
             self.stats["tokens_served"] += len(reqs)
+            self.stats["prefill_rows_computed"] += n_rows
             self.tracer.event(tm.ADMIT, t, sid=self._sid,
                               tenant=self._tenant, bucket=lp,
-                              rids=[r.rid for r in reqs])
+                              rids=[r.rid for r in reqs], rows=len(reqs),
+                              rows_computed=n_rows)
             self._retire_finished(t)  # budget-1 / instant-EOS requests
 
     # --- chunked prefill ----------------------------------------------------
@@ -827,7 +859,7 @@ class ServingEngine:
         assert self._clock >= lp  # clock starts at max_prompt_len, only grows
         bp = self.ec.max_slots
         toks = np.zeros((bp, lp), np.int32)
-        off = np.full(bp, lp, np.int32)  # sentinel: rows not ours stay masked
+        off = np.full(bp, lp, np.int32)
         slots = free[: len(reqs)]
         t = self._stamp(now)
         by_base: Dict[int, Tuple[List[Request], List[int]]] = {}
@@ -949,10 +981,10 @@ class ServingEngine:
 
     def _advance_chunks(self, now: Optional[float] = None) -> bool:
         """Advance every in-flight chunked admission by ONE chunk, merging
-        admissions of the same (chunk len, prompt bucket) class into a
-        single program call (per-row start positions): trickled
-        single-request admissions share the pinned program width instead of
-        each paying a full-width call per chunk."""
+        admissions of the same (chunk len, prompt bucket) class into the
+        same program calls (per-row start positions): trickled
+        single-request admissions share a call instead of each paying one
+        per chunk."""
         if not self._chunk_q:
             return False
         classes: Dict[Tuple[int, int], List[_ChunkAdmission]] = {}
@@ -964,20 +996,22 @@ class ServingEngine:
         return True
 
     def _get_chunk(self, c: int, lp: int):
-        """Jitted chunk executable, one per (chunk length, prompt bucket):
-        the program touches only the ring prefix [0, lp), so each chunk
-        costs what its share of the bucket's monolithic prefill would — the
-        compile-once bound is #chunk buckets + 1 segment."""
+        """Jitted chunk executable, one per (chunk length, prompt bucket),
+        row_tile(max_slots, c) rows wide: the program touches only the ring
+        prefix [0, lp) of the rows it names, so each chunk costs what its
+        share of the bucket's monolithic prefill would — the compile-once
+        bound is #chunk buckets + 1 segment."""
         key = (c, lp)
         fn = self._chunk_cache.get(key)
         if fn is not None:
             self.stats["prefill_cache_hits"] += 1
             return fn
 
-        def _chunk(p, toks, off, pool, start, _lp=lp):
+        def _chunk(p, toks, off, pool, start, slot_ids, _lp=lp):
             self.stats["prefill_traces"] += 1  # trace-time only
             return lm.prefill_chunk_into_slots(
-                p, toks, pool, start, self.cfg, pos_offset=off, lp=_lp
+                p, toks, pool, start, slot_ids, self.cfg, pos_offset=off,
+                lp=_lp
             )
 
         fn = jax.jit(_chunk, donate_argnums=(3,))
@@ -988,9 +1022,11 @@ class ServingEngine:
     def _chunk_step(self, c: int, lp: int,
                     adms: List[_ChunkAdmission],
                     now: Optional[float] = None) -> None:
-        """Run one chunk for every admission of a (chunk, bucket) class in
-        ONE program call (per-row start); admissions reaching their final
-        chunk flip their rows live (decode starts at the next segment).
+        """Run one chunk for every admission of a (chunk, bucket) class,
+        its rows in calls of the chunk's row tile (per-row start and slot
+        id; all dispatched, the pool chained through them); admissions
+        reaching their final chunk flip their rows live (decode starts at
+        the next segment).
 
         No sync precedes the final chunk's, so the `chunk_dispatch` span,
         PREFILL_CHUNK's `dur` and this call's exec time all measure the
@@ -1001,26 +1037,40 @@ class ServingEngine:
         with self._timed(tm.CHUNK_DISPATCH, now, bucket=lp, chunk=c,
                          rids=rids, cols=cols):
             t0 = time.monotonic()
-            bp = self.ec.max_slots
-            toks = np.zeros((bp, c), np.int32)
-            off = np.full(bp, lp, np.int32)   # sentinel: not ours, masked
-            start = np.zeros(bp, np.int32)
+            slots = [s for adm in adms for s in adm.slots]
+            rt = row_tile(self.ec.max_slots, c)
+            n_rows = -(-len(slots) // rt) * rt
+            toks = np.zeros((n_rows, c), np.int32)
+            # padding rows: fully masked (offset lp) and naming a row past
+            # the pool, so nothing of theirs is read or written
+            off = np.full(n_rows, lp, np.int32)
+            start = np.zeros(n_rows, np.int32)
+            sids = np.full(n_rows, self.ec.max_slots, np.int32)
+            i = 0
             for adm, col in zip(adms, cols):
                 for s in adm.slots:
-                    toks[s] = adm.toks[s, col:col + c]
-                    off[s] = adm.off[s]
-                    start[s] = col
-            tok0, self._pool = self._get_chunk(c, lp)(
-                self.params, self._put(toks), self._put(off), self._pool,
-                self._put(start),
-            )
+                    toks[i] = adm.toks[s, col:col + c]
+                    off[i] = adm.off[s]
+                    start[i] = col
+                    sids[i] = s
+                    i += 1
+            fn = self._get_chunk(c, lp)
+            outs = []
+            for i in range(0, n_rows, rt):
+                tok, self._pool = fn(
+                    self.params, self._put(toks[i:i + rt]),
+                    self._put(off[i:i + rt]), self._pool,
+                    self._put(start[i:i + rt]), self._put(sids[i:i + rt]),
+                )
+                outs.append(tok)
             exec_s = time.monotonic() - t0
         self.batch_exec_s.append(exec_s)
         self._h_exec.observe(exec_s)
+        self.stats["prefill_rows_computed"] += n_rows
         self.tracer.event(
             tm.PREFILL_CHUNK, self._stamp(now), sid=self._sid,
-            tenant=self._tenant, bucket=lp, chunk=c,
-            rows=sum(len(a.slots) for a in adms), rids=rids,
+            tenant=self._tenant, bucket=lp, chunk=c, rows=len(slots),
+            rows_computed=n_rows, rids=rids,
             dur=None if self._virtual else exec_s)
         finished: List[_ChunkAdmission] = []
         for adm in adms:
@@ -1034,7 +1084,7 @@ class ServingEngine:
             return
         # final chunk: column lp-1 is every row's last true prompt position,
         # so its greedy tokens seed decode exactly like prefill_into_slots
-        tok0 = np.asarray(tok0)
+        tok0 = dict(zip(slots, np.concatenate([np.asarray(t) for t in outs])))
         t = self._stamp(now)
         for adm in finished:
             for s in adm.slots:
@@ -1042,7 +1092,7 @@ class ServingEngine:
                 n = adm.lp - int(adm.off[s])
                 self._pool_off[s] = self._clock - n
                 self._tok[s] = tok0[s]
-                st.produced = [int(tok0[s, 0])]
+                st.produced = [int(tok0[s][0])]
                 st.live = True
                 if s not in adm.stamped:  # scatter-stamped rows keep theirs
                     st.req.first_token_at = t  # TTFT: final chunk greedy tok
