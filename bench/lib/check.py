@@ -2,7 +2,9 @@
 
 Served tokens: after the window, a sample drawn from the seed of the
 requests the window finished, the longest among them, until it holds
-`sample_tokens` served tokens. The float32 reference runs once over each
+`sample_tokens` served tokens; on a fleet, topped up in the same seeded
+order until every slice that finished a request has one in it. The
+float32 reference runs once over each
 prompt with its served tokens (teacher forcing) and the number compared is
 the widest gap by which a served token's reference logit lies below the
 reference's best logit at that position. Greedy decoding serves the
@@ -21,14 +23,17 @@ float32 front end.
 """
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import numpy as np
 
 from bench.reference import audio_frontend
 
 
-def token_sample(served, seed: int, want_tokens: int) -> List:
+def token_sample(served, seed: int, want_tokens: int,
+                 slice_of: Optional[Dict[int, str]] = None) -> List:
+    """`slice_of` ({rid: slice}) names the slice that served each request
+    on a fleet; None on one engine."""
     done = [s for s in served if s.status == "completed" and s.tokens is not None
             and len(s.tokens)]
     if not done:
@@ -43,6 +48,22 @@ def token_sample(served, seed: int, want_tokens: int) -> List:
             break
         out.append(rest[i])
         n += len(rest[i].tokens)
+    if slice_of:
+        have = {slice_of.get(s.plan.index) for s in out}
+        for i in order:
+            sl = slice_of.get(rest[i].plan.index)
+            if sl is not None and sl not in have:
+                out.append(rest[i])
+                have.add(sl)
+    return out
+
+
+def retiring_slice(events) -> Dict[int, str]:
+    """{rid: slice} from the first RETIRE event of each request."""
+    out: Dict[int, str] = {}
+    for e in events:
+        if e.kind == "retire" and e.rid is not None:
+            out.setdefault(e.rid, e.sid)
     return out
 
 

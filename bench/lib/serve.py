@@ -1,6 +1,6 @@
 """The system under test and the client that drives it.
 
-`build` builds one slice through the program's own entry point,
+`build` builds the cell's server through the program's own entry point,
 `repro.serving.runtime.build_pipelined_runtime` (a continuous-batching
 `ServingEngine` with the policy `build_engine` derives, behind a
 wall-clock `PipelinedRuntime`, with a wall-clock `DpuService` running the
@@ -9,14 +9,23 @@ it the benchmark's own weights. `build_engine` draws a parameter tree of
 its own before it takes `params`; while it builds, that draw returns the
 benchmark's tree instead, so no second tree is ever allocated.
 
+A cell on N > 1 chips, over a configuration that one chip holds whole,
+builds a fleet: `build_pipelined_runtime(n_slices=N)` gives a
+`MultiSliceEngine` with one one-chip replica (`ServingEngine`) per chip
+behind one queue and the one DPU service; the program copies the
+benchmark's tree onto each chip. `System.engines` lists the engines that
+own slot pools: the one engine, or the fleet's slices.
+
 `warm` compiles every program the cell's traffic can use, `drive` is the
 open- or closed-loop client of the measured window, and `Probe` wraps the
-calls into the runtime step, the DPU service and the engine's admit,
+calls into the runtime step, the DPU service and every engine's admit,
 chunk and segment programs with profiler spans in a traced run, recording
 the useful work each call did.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import threading
 import time
 from dataclasses import dataclass, field, replace
@@ -33,23 +42,57 @@ MODEL_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
 
 
 def model_config(config: dict):
-    """The program's registered model with every size the file states."""
+    """The program's registered model with every size the file states:
+    the keys of MODEL_KEYS at the top level of the file, then any further
+    field of the program's `ModelConfig` under `model_fields` (experts,
+    layer pattern, multipliers, ...). A `model_fields` key that is no
+    field, or that MODEL_KEYS already covers, is an error."""
     from repro.configs.base import get_config
 
     base = get_config(config["model"])
-    return replace(base, **{k: config[k] for k in MODEL_KEYS if k in config})
+    extra = dict(config.get("model_fields") or {})
+    known = {f.name for f in dataclasses.fields(base)}
+    bad = sorted(k for k in extra if k not in known or k in MODEL_KEYS)
+    if bad:
+        raise ValueError(f"{config.get('name')}: model_fields {bad} are not "
+                         "ModelConfig fields outside MODEL_KEYS")
+    cfg = replace(base, **{k: config[k] for k in MODEL_KEYS if k in config})
+    return replace(cfg, **{k: tuple(v) if isinstance(v, list) else v
+                           for k, v in extra.items()})
+
+
+def fleet_size(config: dict, chips: int) -> int:
+    """Replicas a cell on `chips` chips serves: one per chip, where one
+    chip holds the whole model (the file reduces nothing); 1 for a
+    one-chip cell. A share of a model (a pipeline stage, an expert share)
+    on several chips needs a sharded build, which the harness has not."""
+    if chips > 1 and config.get("reduced"):
+        raise ValueError(
+            f"{config.get('name')} holds a share of its model (reduced "
+            f"{config['reduced']}): {chips} chips would need a sharded "
+            "build, not replicas")
+    return int(chips)
 
 
 @dataclass
 class System:
     cfg: object
-    engine: object
+    engine: object                  # the runtime's engine (or fleet)
     runtime: object
     service: object
     ec: object
+    engines: list                   # the engines with slot pools
 
 
-def build(config: dict, params, with_audio: bool) -> System:
+def slice_engines(engine) -> list:
+    """The engines that own slot pools: a fleet's slices, in slice order,
+    or the engine itself."""
+    fleet = getattr(engine, "engines", None)
+    return [fleet[k] for k in sorted(fleet)] if fleet is not None \
+        else [engine]
+
+
+def build(config: dict, params, with_audio: bool, chips: int = 1) -> System:
     from unittest import mock
 
     from repro.core.dpu.runtime import DpuConfig
@@ -68,24 +111,94 @@ def build(config: dict, params, with_audio: bool) -> System:
             dpu=DpuConfig(modality="audio", backend="dpu"), clock="wall",
             **config.get("dpu_service", {})))
     rc = RuntimeConfig(clock="wall", **config.get("runtime", {}))
+    n = fleet_size(config, chips)
+    fleet = {"n_slices": n} if n > 1 else {}
     with mock.patch.object(engine_mod.api, "init_params",
                            lambda *a, **k: params):
         runtime = build_pipelined_runtime(cfg, ec=ec, service=service, rc=rc,
-                                          params=params)
+                                          params=params, **fleet)
     engine = runtime.engine
-    if engine.params is not params:
-        raise RuntimeError("build_pipelined_runtime did not serve the "
-                           "benchmark's weights")
-    return System(cfg, engine, runtime, service, engine.ec)
+    engines = slice_engines(engine)
+    if n == 1:
+        if engine.params is not params:
+            raise RuntimeError("build_pipelined_runtime did not serve the "
+                               "benchmark's weights")
+    else:
+        check_replicas(engines, params, n)
+    return System(cfg, engine, runtime, service, engines[0].ec, engines)
+
+
+@functools.lru_cache(maxsize=None)
+def _fingerprint_fn():
+    import jax
+    import jax.numpy as jnp
+
+    uint = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}
+
+    def leaf(x):
+        u = jax.lax.bitcast_convert_type(x, uint[x.dtype.itemsize])
+        u = u.reshape(-1).astype(jnp.uint32)
+        i = jnp.arange(u.shape[0], dtype=jnp.uint32)
+        return jnp.stack([u.sum(dtype=jnp.uint32),
+                          (u * (i * jnp.uint32(2654435761) + 1)).sum(
+                              dtype=jnp.uint32)])
+
+    return jax.jit(lambda tree: jax.tree.map(leaf, tree))
+
+
+def fingerprint(tree) -> list:
+    """Two wrapping uint32 sums of each leaf's bits (plain, and weighted by
+    position), computed where the leaf lives: equal trees read equal."""
+    import jax
+
+    return [np.asarray(x).tolist()
+            for x in jax.tree.leaves(_fingerprint_fn()(tree))]
+
+
+def check_replicas(engines, params, n: int) -> None:
+    """A fleet serves the benchmark's weights on every slice: n slices,
+    each tree on one chip of its own, leaf for leaf the tree the benchmark
+    made (same structure, shapes, types and bits)."""
+    import jax
+
+    if len(engines) != n:
+        raise RuntimeError(f"the fleet has {len(engines)} slices, not {n}")
+    want = jax.tree.structure(params)
+    shapes = [(x.shape, x.dtype) for x in jax.tree.leaves(params)]
+    bits = fingerprint(params)
+    seen = set()
+    for i, e in enumerate(engines):
+        leaves = jax.tree.leaves(e.params)
+        devs = {d for x in leaves for d in x.devices()}
+        if jax.tree.structure(e.params) != want or \
+                [(x.shape, x.dtype) for x in leaves] != shapes or \
+                len(devs) != 1 or devs & seen or fingerprint(e.params) != bits:
+            raise RuntimeError(f"slice {i} does not serve the benchmark's "
+                               "weights on a chip of its own")
+        seen |= devs
 
 
 def tokens_served(engine) -> int:
     """Output tokens the engine has produced so far: those of the requests
-    it completed, and those its live rows hold. Read from the engine's
-    slot table between two steps."""
+    it completed, and those its live rows hold, summed over a fleet's
+    slices (a fleet records each completed request once, whichever slice
+    served it). Read from the slot tables between two steps."""
     done = sum(len(r.payload) for r in engine.completed)
-    return done + sum(len(st.produced) for st in engine._slots
-                      if st is not None)
+    return done + sum(len(st.produced) for e in slice_engines(engine)
+                      for st in e._slots if st is not None)
+
+
+def counters(engine) -> dict:
+    """The engines' counters, summed over a fleet's slices, with the
+    fleet's own counters beside them as `fleet.<name>`."""
+    engines = slice_engines(engine)
+    out = dict(engines[0].stats.items())
+    for e in engines[1:]:
+        for k, v in e.stats.items():
+            out[k] = out.get(k, 0) + v
+    if engine is not engines[0]:
+        out.update({f"fleet.{k}": v for k, v in engine.stats.items()})
+    return out
 
 
 def next_pow2(n: int) -> int:
@@ -104,8 +217,11 @@ def prompt_buckets(ec, lo: int, hi: int) -> List[int]:
 def warm(sysm: System, traffic: dict, rng: np.random.Generator) -> int:
     """Compile every program the traffic can reach: one request per prompt
     bucket through the runtime (admit / chunk / segment programs), and the
-    fused DPU program for every clip length x power-of-two group size.
-    Returns the number of warm-up requests served."""
+    fused DPU program for every clip length x power-of-two group size. A
+    fleet's dispatcher picks the slice a request lands on, so each slice
+    then takes one request per prompt bucket of its own, offered to it
+    directly: no slice compiles inside the window. Returns the number of
+    warm-up requests served."""
     import jax.numpy as jnp
 
     from repro.core.batching.buckets import Request
@@ -123,21 +239,33 @@ def warm(sysm: System, traffic: dict, rng: np.random.Generator) -> int:
                 xs = [np.zeros(n, np.float32)] * m
                 np.asarray(kops.audio_pipeline_batch(jnp.stack(xs)))
             m *= 2
-    reqs = []
-    for i, lp in enumerate(prompt_buckets(sysm.ec, lo, hi)):
-        n = min(lp, hi)
-        reqs.append(Request(
-            rid=-1 - i, arrival=time.monotonic(), length=float(n),
+    buckets = prompt_buckets(sysm.ec, lo, hi)
+
+    def requests(first_rid, audio):
+        return [Request(
+            rid=first_rid - i, arrival=time.monotonic(), length=float(n),
             prompt=rng.integers(0, sysm.cfg.vocab, n).astype(np.int32),
             payload=(rng.standard_normal(clips[i % len(clips)],
                                          dtype=np.float32)
-                     if clips else None),
-            max_new_tokens=2))
+                     if audio else None),
+            max_new_tokens=2)
+            for i, n in enumerate(min(lp, hi) for lp in buckets)]
+
+    reqs = requests(-1, bool(clips))
     sysm.runtime.submit(reqs)
     while sysm.runtime.busy():
         if not sysm.runtime.step():
             time.sleep(0.001)
-    return len(reqs)
+    n_warm = len(reqs)
+    if len(sysm.engines) > 1:
+        for k, e in enumerate(sysm.engines):
+            own = requests(-1 - (k + 1) * len(buckets), False)
+            e.offer(own)
+            while e.busy():
+                e.step()
+            e.completed = []
+            n_warm += len(own)
+    return n_warm
 
 
 @dataclass
@@ -312,13 +440,12 @@ class Probe:
         self._lock = threading.Lock()
         self.fl = flops_mod
         self.mc = config_numbers(sysm.cfg)
-        eng, rt = sysm.engine, sysm.runtime
-        self._wrap(rt, "step", "bench.runtime_step")
-        self._wrap(eng, "_admit", "bench.admit", self._admit_flops)
-        self._wrap(eng, "_chunk_step", "bench.chunk", self._chunk_flops)
-        self._eng = eng
-        self._wrap(eng, "_decode_segment", "bench.segment",
-                   self._segment_flops)
+        self._wrap(sysm.runtime, "step", "bench.runtime_step")
+        for eng in sysm.engines:
+            self._wrap(eng, "_admit", "bench.admit", self._admit_flops)
+            self._wrap(eng, "_chunk_step", "bench.chunk", self._chunk_flops)
+            self._wrap(eng, "_decode_segment", "bench.segment",
+                       functools.partial(self._segment_flops, eng))
         if sysm.service is not None:
             self._wrap(sysm.service, "step", "bench.dpu_step")
             self._dpu_cost = dpu_cost
@@ -362,9 +489,9 @@ class Probe:
                     f += self.fl.head_flops(self.mc)
         return (f,)
 
-    def _segment_flops(self, steps, now=None):
+    def _segment_flops(self, eng, steps, now=None):
         f, live = 0.0, 0
-        for st in self._eng._slots:
+        for st in eng._slots:
             if st is None or not st.live:
                 continue
             live += 1
@@ -384,6 +511,6 @@ class Probe:
 
 
 def config_numbers(cfg) -> dict:
-    """The model's sizes as a plain dict (what flops/ and reference/ read)."""
-    return {k: getattr(cfg, k) for k in MODEL_KEYS if hasattr(cfg, k)} | {
-        "head_dim": cfg.hd}
+    """Every field of the model's `ModelConfig` as a plain dict (what
+    flops/ and reference/ read), with `head_dim` resolved."""
+    return dataclasses.asdict(cfg) | {"head_dim": cfg.hd}

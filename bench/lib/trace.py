@@ -178,10 +178,43 @@ def read(log_dir: str, window_span: str = "bench.window") -> Trace:
                  window=(win[0][1], win[0][2]))
 
 
+def busy_intervals(dev: DeviceTrace) -> List[Interval]:
+    """The chip's op executions, or its program executions where the
+    trace holds no ops."""
+    return [(s, e) for _, s, e in dev.ops] or \
+        [(s, e) for _, s, e in dev.modules]
+
+
 def device_busy(tr: Trace) -> float:
     """Busy seconds averaged over the chips in the trace."""
     if not tr.devices:
         return 0.0
-    return float(np.mean([busy_s([(s, e) for _, s, e in d.ops]
-                                 or [(s, e) for _, s, e in d.modules],
-                                 tr.window) for d in tr.devices]))
+    return float(np.mean([busy_s(busy_intervals(d), tr.window)
+                          for d in tr.devices]))
+
+
+def chip_label(dev: DeviceTrace) -> str:
+    """'/device:TPU:2' -> 'tpu2'."""
+    return "tpu" + dev.name.rsplit(":", 1)[-1]
+
+
+def breakdown(tr: Trace, top: int = 10) -> Dict[str, List[list]]:
+    """The device ops that took most time and the longest idle gaps by
+    what the host was doing. One chip: as `top_ops` and `attribute` give
+    them. Several chips: both lists over every chip, each name prefixed
+    with its chip ('tpu<i>/')."""
+    if len(tr.devices) == 1:
+        d = tr.devices[0]
+        return {"device_ops": top_ops(d, tr.window, top),
+                "idle_gaps": attribute(idle_gaps(
+                    [(s, e) for _, s, e in d.ops], tr.window),
+                    tr.host_spans, top)}
+    ops: List[list] = []
+    gaps: List[list] = []
+    for d in tr.devices:
+        tag = chip_label(d) + "/"
+        ops += [[tag + k, v] for k, v in top_ops(d, tr.window, top)]
+        g = idle_gaps([(s, e) for _, s, e in d.ops], tr.window)
+        gaps += [[tag + k, v] for k, v in attribute(g, tr.host_spans, top)]
+    return {"device_ops": sorted(ops, key=lambda kv: -kv[1])[:top],
+            "idle_gaps": sorted(gaps, key=lambda kv: -kv[1])[:top]}

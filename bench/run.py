@@ -24,6 +24,7 @@ import time
 T_START = time.monotonic()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -64,7 +65,7 @@ class Hooks:
 
     def _counters(self):
         eng = self.sysm.engine
-        return {"stats": dict(eng.stats.items()), "at": time.monotonic(),
+        return {"stats": serve.counters(eng), "at": time.monotonic(),
                 "tokens": serve.tokens_served(eng)}
 
     def window_open(self, t0, t1):
@@ -153,7 +154,7 @@ def run_cell(cell, seed, seconds, trace=False, control=False, rate=None,
         mix["rate_qps"] = float(rate)
     devs = jax.devices()
     dev = devs[0]
-    if not allow_cpu and (dev.platform != "tpu" or len(devs) < cell.chips):
+    if len(devs) < cell.chips or not allow_cpu and dev.platform != "tpu":
         raise SystemExit(f"needs {cell.chips} TPU chip(s); JAX sees "
                          f"{len(devs)} {dev.platform} device(s)")
     peaks = None if dev.platform != "tpu" else peaks_for(dev.device_kind)
@@ -168,7 +169,8 @@ def run_cell(cell, seed, seconds, trace=False, control=False, rate=None,
     t = time.monotonic()
     params = weights.init_params(cfg, seed, dev)
     t_w = time.monotonic() - t
-    sysm = serve.build(config, params, with_audio=bool(mix.get("audio")))
+    sysm = serve.build(config, params, with_audio=bool(mix.get("audio")),
+                       chips=cell.chips)
     t = time.monotonic()
     n_warm = serve.warm(sysm, mix, np.random.default_rng([seed, 9]))
     t_warm = time.monotonic() - t
@@ -196,7 +198,11 @@ def run_cell(cell, seed, seconds, trace=False, control=False, rate=None,
     in_window = sum(1 for c in compiles[n_compiles_before:] if c <= win.t1)
     memory_peak = None
     if dev.platform == "tpu":
-        memory_peak = int(dev.memory_stats()["peak_bytes_in_use"])
+        peaks_by_chip = [int(d.memory_stats()["peak_bytes_in_use"])
+                         for d in devs[:cell.chips]]
+        memory_peak = max(peaks_by_chip)    # the fullest chip's
+        if cell.chips > 1:
+            log(f"memory peak by chip: {peaks_by_chip}")
     failed = sum(1 for s in win.served if s.status != "completed")
     late = sorted(win.lateness) or [0.0]
     log(f"window {seconds} s: {len(win.served)} requests due, "
@@ -217,6 +223,18 @@ def run_cell(cell, seed, seconds, trace=False, control=False, rate=None,
         f"tokens served in {hooks.end['at'] - hooks.start['at']:.3f} s; "
         f"occupied rows per segment mean {np.mean(occ or [0]):.2f}, max "
         f"{max(occ or [0])} of {slots}")
+    fleet = len(sysm.engines) > 1
+    slice_of = check.retiring_slice(events) if fleet else None
+    if fleet:
+        served_by = {}
+        for s in win.served:
+            k = slice_of.get(s.plan.index)
+            served_by[k] = served_by.get(k, 0) + 1
+        log(f"fleet of {len(sysm.engines)}: window requests by slice "
+            f"{dict(sorted(served_by.items(), key=str))}; fleet counters "
+            + json.dumps({k: hooks.end["stats"][k] - hooks.start["stats"][k]
+                          for k in hooks.end["stats"]
+                          if k.startswith("fleet.")}))
     tr = None
     if trace:
         from bench.lib import trace as tracemod
@@ -226,7 +244,10 @@ def run_cell(cell, seed, seconds, trace=False, control=False, rate=None,
         shutil.rmtree(trace_dir, ignore_errors=True)
         log(f"trace read in {time.monotonic() - t:.3f} s: "
             f"{sum(len(d.ops) for d in tr.devices)} device ops, "
-            f"window {tr.window_s:.6f} s")
+            f"window {tr.window_s:.6f} s; busy by chip "
+            + ", ".join(f"{tracemod.chip_label(d)} "
+                        f"{tracemod.busy_s(tracemod.busy_intervals(d), tr.window):.6f} s"
+                        for d in tr.devices))
     mc = serve.config_numbers(sysm.cfg)
     ctx = SimpleNamespace(
         served=win.served, window=(win.t0, win.t1), events=events,
@@ -238,13 +259,14 @@ def run_cell(cell, seed, seconds, trace=False, control=False, rate=None,
         model=mc)
     # the program's state goes before the reference runs; the weights stay
     sysm.runtime.close()
-    sysm = probe = None
+    sysm = probe = hooks.sysm = None
     gc.collect()
 
     ck = config["check"]
     ref = spec.load_module(cell.bench_dir, "reference", config["reference"])
     t = time.monotonic()
-    sample = check.token_sample(win.served, seed, int(ck["sample_tokens"]))
+    sample = check.token_sample(win.served, seed, int(ck["sample_tokens"]),
+                                slice_of)
     with jax.default_matmul_precision("highest"):
         gap, cgap, n_tok = check.token_gaps(ref, params, sample, mc, control)
     log(f"reference: {len(sample)} requests, {n_tok} served tokens, "
@@ -285,11 +307,7 @@ def run_cell(cell, seed, seconds, trace=False, control=False, rate=None,
         device["busy_s"] = tracemod.device_busy(tr)
         device["window_s"] = tr.window_s
     if trace and tr is not None and tr.devices:
-        gaps = tracemod.idle_gaps(
-            [(s, e) for _, s, e in tr.devices[0].ops], tr.window)
-        out["breakdown"] = {
-            "device_ops": tracemod.top_ops(tr.devices[0], tr.window),
-            "idle_gaps": tracemod.attribute(gaps, tr.host_spans)}
+        out["breakdown"] = tracemod.breakdown(tr)
     if rate is not None:
         calib["knee"] = knee_report(win, config["knee"])
         calib["rate_qps"] = mix["rate_qps"]
@@ -304,7 +322,8 @@ def run_cell(cell, seed, seconds, trace=False, control=False, rate=None,
 def sweep(cell, seed, seconds, rates, unloaded_n=12):
     """Calibration: the cell's unloaded latencies (one request at a time)
     and, for each open-loop rate, the share of requests meeting the knee
-    limits, from one process (one set-up)."""
+    limits, from one process (one set-up). Each drive numbers its
+    requests after the last one's: a fleet records a request id once."""
     import jax
 
     config, mix = cell.config, dict(cell.traffic)
@@ -312,13 +331,15 @@ def sweep(cell, seed, seconds, rates, unloaded_n=12):
     cfg = serve.model_config(config)
     slots = int(config["engine"]["max_slots"])
     params = weights.init_params(cfg, seed, dev)
-    sysm = serve.build(config, params, with_audio=bool(mix.get("audio")))
+    sysm = serve.build(config, params, with_audio=bool(mix.get("audio")),
+                       chips=cell.chips)
     serve.warm(sysm, mix, np.random.default_rng([seed, 9]))
     one = dict(mix, kind="closed", clients_per_slot=1,
                requests_per_client=unloaded_n)
     plan1 = [p for p in traffic.plan(one, seed, seconds, cfg.vocab, slots)
              if p.client == 0]
     win = serve.drive(sysm, one, plan1, 600.0, 60.0)
+    first = max(p.index for p in plan1) + 1
     done = [s for s in win.served if s.status == "completed"]
     ttft = [s.req.first_token_at - s.due for s in done]
     tpot = [(s.req.completed_at - s.req.first_token_at) / (len(s.tokens) - 1)
@@ -334,7 +355,9 @@ def sweep(cell, seed, seconds, rates, unloaded_n=12):
            "limits": limits, "rates": []}
     for r in rates:
         m = dict(mix, rate_qps=float(r))
-        planned = traffic.plan(m, seed, seconds, cfg.vocab, slots)
+        planned = [dataclasses.replace(p, index=p.index + first)
+                   for p in traffic.plan(m, seed, seconds, cfg.vocab, slots)]
+        first += len(planned)
         win = serve.drive(sysm, m, planned, seconds, float(m.get("drain_s", 60)))
         rep = knee_report(win, limits)
         ttft = [s.req.first_token_at - s.due if s.status == "completed"
